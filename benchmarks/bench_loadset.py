@@ -10,15 +10,9 @@ Measures wall time on 8 simulated machines + analytic bytes-moved.
 """
 from __future__ import annotations
 
-import pathlib
-import subprocess
-import sys
-
-SRC = str(pathlib.Path(__file__).resolve().parents[1] / "src")
+from benchmarks.common import run_cpu_worker
 
 WORKER = r"""
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import time
 import numpy as np
 from repro.api import GraphSession
@@ -61,18 +55,7 @@ for use_ring, name in ((False, "allgather"), (True, "ring")):
 
 
 def main() -> None:
-    proc = subprocess.run(
-        [sys.executable, "-c", WORKER],
-        capture_output=True, text=True,
-        env={"PYTHONPATH": SRC, "PATH": "/usr/bin:/bin"},
-        timeout=2000,
-    )
-    if proc.returncode != 0:
-        print(f"loadset_bench_failed,0.0,{proc.stderr[-200:].strip()!r}")
-        return
-    for line in proc.stdout.strip().splitlines():
-        if line.startswith(("loadset_", "#")):
-            print(line)
+    run_cpu_worker(WORKER, ("loadset_", "#"), timeout=2000)
 
 
 if __name__ == "__main__":

@@ -9,9 +9,9 @@ from benchmarks.common import (
     build_matcher,
     dfs_query,
     emit,
-    patents_like,
     random_query,
 )
+from repro.graphstore.generators import patents_like
 
 
 def main(scale: float = 0.008, n_queries: int = 3) -> None:

@@ -24,9 +24,10 @@ import pathlib
 
 
 def main(json_out: "str | None" = None) -> None:
-    from repro.analysis.roofline import engine_rooflines
+    from repro.analysis.roofline import PEAKS, engine_rooflines
 
-    rooflines = engine_rooflines()
+    # a static report wherever it runs: scored at the v5e's published peaks
+    rooflines = engine_rooflines(peaks=PEAKS["TPU v5 lite"])
     doc = {}
     for target, r in rooflines.items():
         # engine:local:jnp:match -> roofline_local_jnp_match

@@ -5,15 +5,9 @@ while the bench session keeps one.
 """
 from __future__ import annotations
 
-import pathlib
-import subprocess
-import sys
-
-SRC = str(pathlib.Path(__file__).resolve().parents[1] / "src")
+from benchmarks.common import run_cpu_worker
 
 WORKER = r"""
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import time
 import numpy as np
 from repro.api import GraphSession
@@ -44,19 +38,7 @@ for S in (1, 2, 4, 8):
 
 
 def main() -> None:
-    proc = subprocess.run(
-        [sys.executable, "-c", WORKER],
-        capture_output=True,
-        text=True,
-        env={"PYTHONPATH": SRC, "PATH": "/usr/bin:/bin"},
-        timeout=3000,
-    )
-    if proc.returncode != 0:
-        print(f"speedup_bench_failed,0.0,{proc.stderr[-200:].strip()!r}")
-        return
-    for line in proc.stdout.strip().splitlines():
-        if line.startswith("speedup_"):
-            print(line)
+    run_cpu_worker(WORKER, ("speedup_",), timeout=3000)
 
 
 if __name__ == "__main__":

@@ -9,6 +9,9 @@ scripts); matching goes through the `GraphSession` facade.
 """
 from __future__ import annotations
 
+import pathlib
+import subprocess
+import sys
 import time
 
 import numpy as np
@@ -16,6 +19,33 @@ import numpy as np
 from repro.api import GraphSession
 from repro.graphstore import PartitionedGraph, generators
 from repro.workloads import dfs_query, random_query  # noqa: F401  (re-export)
+
+SRC = str(pathlib.Path(__file__).resolve().parents[1] / "src")
+
+
+def run_cpu_worker(code: str, prefixes: tuple[str, ...], *, timeout: float) -> None:
+    """Run a multi-device figure reproduction in a child process on 8
+    forced host-CPU devices, echoing the child's result rows that start
+    with ``prefixes``. The child is pinned to the CPU (``JAX_PLATFORMS``),
+    so it never reaches for a chip the parent may hold. Raises if the
+    child fails, so the suite counts as failed."""
+    proc = subprocess.run(
+        [sys.executable, "-c", code],
+        capture_output=True,
+        text=True,
+        env={
+            "PYTHONPATH": SRC,
+            "PATH": "/usr/bin:/bin",
+            "JAX_PLATFORMS": "cpu",
+            "XLA_FLAGS": "--xla_force_host_platform_device_count=8",
+        },
+        timeout=timeout,
+    )
+    if proc.returncode != 0:
+        raise RuntimeError(f"worker failed: {proc.stderr[-400:].strip()}")
+    for line in proc.stdout.strip().splitlines():
+        if line.startswith(prefixes):
+            print(line)
 
 
 def emit(name: str, us_per_call: float, derived: str = "") -> None:
@@ -28,14 +58,6 @@ def timed(fn, *, repeats: int = 3):
     for _ in range(repeats):
         fn()
     return (time.perf_counter() - t0) / repeats
-
-
-def patents_like(scale: float = 1.0, seed: int = 0):
-    """US-Patents-shaped R-MAT: 3.77M nodes, 16.5M edges, 418 labels
-    (scaled down by ``scale`` for CPU budgets)."""
-    n = max(int(3_774_768 * scale), 1000)
-    m = max(int(16_522_438 * scale), 4000)
-    return generators.rmat(n, m, 418, seed=seed)
 
 
 def build_matcher(g, n_shards: int = 1) -> GraphSession:

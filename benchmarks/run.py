@@ -74,6 +74,9 @@ def main() -> None:
                          "(default benchmarks/snapshots/BENCH_<date>.json)")
     args = ap.parse_args()
 
+    from repro.runtime import compile_cache
+
+    compile_cache.enable()
     from benchmarks import (
         bench_density,
         bench_graph_size,
@@ -120,6 +123,7 @@ def main() -> None:
 
     only = set(args.only.split(",")) if args.only else None
     snapshot_rows: list[dict] = []
+    failed: list[str] = []
     print("name,us_per_call,derived")
     for name, fn in suites.items():
         if only is not None and name not in only:
@@ -129,9 +133,10 @@ def main() -> None:
         try:
             with contextlib.redirect_stdout(buf):
                 fn()
-        except Exception:  # noqa: BLE001 — report, keep the suite running
+        except Exception:  # noqa: BLE001 — report, run the other suites
             buf.write(f"{name}_FAILED,0.0,\n")
             traceback.print_exc()
+            failed.append(name)
         text = buf.getvalue()
         sys.stdout.write(text)
         snapshot_rows.extend(_parse_rows(name, text))
@@ -157,6 +162,9 @@ def main() -> None:
         path.parent.mkdir(parents=True, exist_ok=True)
         path.write_text(json.dumps(doc, indent=2) + "\n")
         print(f"# snapshot -> {path}", file=sys.stderr)
+    if failed:
+        print(f"# failed suites: {','.join(failed)}", file=sys.stderr)
+        sys.exit(1)
 
 
 if __name__ == "__main__":

@@ -6,11 +6,12 @@ all behind the same `GraphSession` facade as the local engine.
 """
 import os
 
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
+import jax
 
-from repro.api import GraphSession  # noqa: E402
-from repro.core import QueryGraph  # noqa: E402
-from repro.graphstore import PartitionedGraph, generators  # noqa: E402
+from repro.api import GraphSession
+from repro.core import QueryGraph
+from repro.graphstore import PartitionedGraph, generators
+from repro.runtime import compile_cache
 
 
 def main() -> None:
@@ -42,4 +43,9 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    # 8 simulated machines on host-CPU devices; set before JAX first
+    # touches a device, and never on the chip
+    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
+    jax.config.update("jax_platforms", "cpu")
+    compile_cache.enable()
     main()

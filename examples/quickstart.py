@@ -11,6 +11,7 @@ paper's pipelined first-K semantics (§6.1).
 from repro.api import GraphSession
 from repro.core import QueryGraph, stwig_order_selection
 from repro.graphstore import generators
+from repro.runtime import compile_cache
 
 
 def main() -> None:
@@ -60,4 +61,5 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    compile_cache.enable()
     main()

@@ -17,6 +17,7 @@ import numpy as np
 
 from repro.api import GraphSession, summarize_outcomes
 from repro.graphstore import generators
+from repro.runtime import compile_cache
 from repro.workloads import mixed_workload
 
 
@@ -68,4 +69,5 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    compile_cache.enable()
     main()

@@ -15,7 +15,7 @@ from repro.checkpoint import Checkpointer
 from repro.configs.base import LMConfig
 from repro.data import lm_batch
 from repro.models import transformer as tf
-from repro.runtime import SimulatedPreemption, TrainSupervisor
+from repro.runtime import SimulatedPreemption, TrainSupervisor, compile_cache
 from repro.train import make_train_step
 
 
@@ -73,4 +73,5 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    compile_cache.enable()
     main()

@@ -12,7 +12,9 @@ all-gather/reduce-scatter (n−1)/n, permute 1×). XLA reports the per-device
 partitioned module, so totals are already per-chip; the roofline divides by
 chips only when given whole-program numbers (``per_device=False``).
 
-Hardware constants: TPU v5e — 197 TFLOP/s bf16, 819 GB/s HBM, ~50 GB/s/link ICI.
+Hardware constants come from `PEAKS`, keyed by ``jax.Device.device_kind``;
+a kind that is not in the table is an error, never a default. A static
+report made off the chip passes its peaks explicitly.
 
 `engine_rooflines` points the same three-term model at the matcher itself:
 per-executable FLOPs / peak bytes / collective bytes come from the
@@ -26,9 +28,32 @@ from __future__ import annotations
 import dataclasses
 import re
 
-PEAK_FLOPS = 197e12
-HBM_BW = 819e9
-ICI_BW = 50e9
+
+@dataclasses.dataclass(frozen=True)
+class Peaks:
+    """Published per-chip peaks."""
+
+    flops: float    # bf16 FLOP/s
+    hbm_bw: float   # HBM bytes/s
+    ici_bw: float   # chip-to-chip interconnect bytes/s
+
+
+# Google Cloud documentation, "TPU v5e" (cloud.google.com/tpu/docs/v5e):
+# 197 TFLOP/s bf16, 16 GB HBM at 819 GB/s, 1,600 Gbit/s interconnect.
+# JAX reports a v5e as "TPU v5 lite".
+_V5E = Peaks(flops=197e12, hbm_bw=819e9, ici_bw=1600e9 / 8)
+PEAKS: dict[str, Peaks] = {"TPU v5 lite": _V5E, "TPU v5e": _V5E}
+
+
+def peaks_for(device_kind: str) -> Peaks:
+    """The published peaks of ``device_kind``; unknown kinds are an error."""
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no published peaks for device kind {device_kind!r}; known: "
+            f"{sorted(PEAKS)} (pass explicit peaks for a static report)"
+        ) from None
 
 _DTYPE_BYTES = {
     "pred": 1, "s8": 1, "u8": 1, "s16": 2, "u16": 2, "bf16": 2, "f16": 2,
@@ -86,19 +111,20 @@ class Roofline:
     hbm_bytes: float         # per-device bytes accessed
     collective_bytes: float  # per-device collective bytes moved
     n_chips: int
+    peaks: Peaks
     model_flops: float = 0.0  # 6·N·D (or 6·N_active·D) whole-step model flops
 
     @property
     def t_compute(self) -> float:
-        return self.flops / PEAK_FLOPS
+        return self.flops / self.peaks.flops
 
     @property
     def t_memory(self) -> float:
-        return self.hbm_bytes / HBM_BW
+        return self.hbm_bytes / self.peaks.hbm_bw
 
     @property
     def t_collective(self) -> float:
-        return self.collective_bytes / ICI_BW
+        return self.collective_bytes / self.peaks.ici_bw
 
     @property
     def bottleneck(self) -> str:
@@ -120,7 +146,7 @@ class Roofline:
         """Fraction of the step's time the dominant term says is 'useful
         peak': model_flops/chips/PEAK divided by the bounding term."""
         bound = max(self.t_compute, self.t_memory, self.t_collective)
-        ideal = (self.model_flops / self.n_chips) / PEAK_FLOPS
+        ideal = (self.model_flops / self.n_chips) / self.peaks.flops
         return (ideal / bound) if bound > 0 else 0.0
 
     def to_dict(self) -> dict:
@@ -146,6 +172,7 @@ def engine_rooflines(
     *,
     scale: int = 1,
     n_chips: int | None = None,
+    peaks: Peaks | None = None,
 ) -> "dict[str, Roofline]":
     """Per-entry-point rooflines for the matcher engines, attributed from
     the staticcheck cost model — nothing executes beyond the tiny probe.
@@ -169,6 +196,8 @@ def engine_rooflines(
         ``roofline_fraction`` reads as "fraction of the bounding term the
         pure-compute time accounts for" (1.0 ⇔ compute-bound).
 
+    ``peaks`` defaults to those of the first device's kind (`peaks_for`).
+
     Returns ``{target: Roofline}`` with targets like
     ``engine:local:jnp:match``.
     """
@@ -180,6 +209,7 @@ def engine_rooflines(
     backends = tuple(backends or _engines.ENGINE_BACKENDS)
     kernels = tuple(kernels or _engines.KERNEL_BACKENDS)
     chips = n_chips if n_chips is not None else jax.device_count()
+    peaks = peaks or peaks_for(jax.devices()[0].device_kind)
 
     worst: dict[str, dict] = {}
     for b in backends:
@@ -201,6 +231,7 @@ def engine_rooflines(
             hbm_bytes=m["peak_bytes"],
             collective_bytes=m["collective_bytes"],
             n_chips=chips,
+            peaks=peaks,
             model_flops=m["flops"],
         )
         for target, m in sorted(worst.items())

@@ -179,7 +179,9 @@ class CompiledQuery:
                 flat = np.concatenate(buf, axis=0) if len(buf) > 1 else buf[0]
                 head, tail = flat[:take], flat[take:]
                 buf, buffered = ([tail], tail.shape[0]) if tail.shape[0] else ([], 0)
-                yield page(head, complete)
+                # the page that meets the limit completes a first-K answer
+                met = bool(limit) and emitted + head.shape[0] >= limit
+                yield page(head, complete or met)
                 if limit and emitted >= limit:
                     return  # early exit: remaining blocks are never joined
         if buffered:

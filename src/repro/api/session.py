@@ -58,11 +58,11 @@ class GraphSession:
         local backend, ``n_shards`` (default: all devices) for sharded.
 
         ``kernels`` selects the kernel backend every dense inner step draws
-        from — ``"auto"`` (Pallas on TPU, jnp elsewhere), ``"jnp"``,
-        ``"pallas"``, ``"pallas-interpret"``, or a registered `Kernels`
-        instance (`repro.core.backend`). The choice keys every cached
-        executable, so sessions can be compared across kernel backends
-        without recompiling each other's programs away.
+        from — ``"auto"`` (the per-op table `TPU_OPS` on TPU, jnp
+        elsewhere), ``"jnp"``, ``"pallas"``, ``"pallas-interpret"``, or a
+        registered `Kernels` instance (`repro.core.backend`). The choice
+        keys every cached executable, so sessions can be compared across
+        kernel backends without recompiling each other's programs away.
 
         ``chaos`` attaches a seeded fault injector
         (`repro.runtime.chaos.ChaosInjector`) to the engine: injected

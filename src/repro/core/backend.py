@@ -17,7 +17,11 @@ Registered backends:
                              `repro.kernels.hash_join`);
   * ``"pallas-interpret"`` — the same kernels in interpret mode: runs on
                              CPU, used by the parity tests in CI;
-  * ``"auto"``             — resolves to ``"pallas"`` on TPU, ``"jnp"``
+  * ``"tpu"``              — one implementation per op, named in the
+                             static table `TPU_OPS`: the Pallas kernel
+                             where it compiles for the chip, the jnp op
+                             where it does not;
+  * ``"auto"``             — resolves to ``"tpu"`` on TPU, ``"jnp"``
                              elsewhere.
 
 Making any step faster now means writing one kernel and registering it —
@@ -300,6 +304,43 @@ class PallasKernels(Kernels):
         return cin_layer(xk, x0, w, interpret=self.interpret)
 
 
+# ------------------------------------------------------- per-op TPU choice
+# What ``kernels="auto"`` runs for each op on a TPU, and the only place that
+# choice is made. An op names "jnp" where its Pallas kernel is refused by the
+# TPU compiler and needs a redesign to pass (message as the v5e compiler
+# gives it); tests/test_tpu_compile.py compiles every entry for a v5e.
+TPU_OPS: dict[str, str] = {
+    "bitset_pack": "pallas",
+    "bitset_unpack": "pallas",
+    # in-kernel 1-D gather of one word per id: "Only 2D gather is supported"
+    "bitset_lookup": "jnp",
+    "candidate_filter": "jnp",
+    # the Pallas op is the jnp bool scatter plus the pack kernel; that
+    # scatter alone takes 10 s to compile at 3.8M bits
+    "bitset_build": "jnp",
+    # in-kernel 1-D gathers ("Only 2D gather is supported"), and `emit`
+    # holds the whole (k, E) prefix arrays in VMEM
+    "stwig_expand": "jnp",
+    # in-kernel 1-D gathers ("Only 2D gather is supported"), and the whole
+    # build side is VMEM-resident
+    "hash_join_probe": "jnp",
+    "cin_layer": "pallas",
+}
+
+
+class PerOpKernels(Kernels):
+    """Each op taken from the registered backend its table names."""
+
+    def __init__(self, name: str, table: dict[str, str]):
+        missing = {c.op for c in OP_CONTRACTS} - set(table)
+        if missing:
+            raise ValueError(f"{name!r} names no implementation for {sorted(missing)}")
+        self.name = name
+        self.table = dict(table)
+        for op, impl in self.table.items():
+            setattr(self, op, getattr(get_kernels(impl), op))
+
+
 # ------------------------------------------------------------------ registry
 _REGISTRY: dict[str, Callable[[], Kernels]] = {}
 _INSTANCES: dict[str, Kernels] = {}
@@ -354,17 +395,22 @@ def get_kernels(name: str) -> Kernels:
 
 def resolve_kernels(spec: "str | Kernels | None" = None) -> Kernels:
     """Normalize a user-facing kernels spec: a `Kernels` instance passes
-    through, ``None`` means ``"auto"``, and ``"auto"`` picks Pallas on TPU
-    and jnp elsewhere (interpret mode is never auto-selected — it is a
-    testing backend)."""
+    through, ``None`` means ``"auto"``, and ``"auto"`` picks the per-op
+    `TPU_OPS` table on TPU and jnp elsewhere (interpret mode is never
+    auto-selected — it is a testing backend)."""
     if isinstance(spec, Kernels):
         return spec
     name = spec or "auto"
     if name == "auto":
-        name = "pallas" if jax.default_backend() == "tpu" else "jnp"
+        if jax.default_backend() == "tpu":
+            return TPU_KERNELS
+        name = "jnp"
     return get_kernels(name)
 
 
 register_backend("jnp", Kernels)
 register_backend("pallas", PallasKernels)
 register_backend("pallas-interpret", lambda: PallasKernels(interpret=True))
+
+# what "auto" runs on a TPU; not a registered name, so not user-selectable
+TPU_KERNELS = PerOpKernels("tpu", TPU_OPS)

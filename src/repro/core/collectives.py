@@ -17,14 +17,13 @@ become:
 from __future__ import annotations
 
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
-
-from repro.compat import axis_size
 
 
 def or_allreduce(words: jnp.ndarray, axis_name: str) -> jnp.ndarray:
     """Bitwise-OR all-reduce across a mesh axis."""
-    n = axis_size(axis_name)
+    n = lax.axis_size(axis_name)
     if n == 1:
         return words
     if n & (n - 1) == 0:
@@ -53,11 +52,14 @@ def gather_load_set(
 ) -> tuple[jnp.ndarray, jnp.ndarray]:
     """Fetch remote STwig tables, keeping rows only from shards in this
     shard's load set. cols (cap, w), valid (cap,), load_row (S,) bool."""
-    S = axis_size(axis_name)
-    g_cols = lax.all_gather(cols, axis_name)          # (S, cap, w)
-    g_valid = lax.all_gather(valid, axis_name)        # (S, cap)
-    g_valid &= load_row[:, None]
-    return g_cols.reshape(S * cols.shape[0], cols.shape[1]), g_valid.reshape(-1)
+    # tiled gathers land as (S*cap, w) / (S*cap,) with no reshape: on the
+    # TPU, flattening the (S, cap, ...) stack of a narrow or bool array is
+    # a relayout that takes the compiler tens of seconds at STwig-table size
+    cap = cols.shape[0]
+    g_cols = lax.all_gather(cols, axis_name, tiled=True)    # (S*cap, w)
+    g_valid = lax.all_gather(valid, axis_name, tiled=True)  # (S*cap,)
+    src = lax.broadcasted_iota(jnp.int32, g_valid.shape, 0) // np.int32(cap)
+    return g_cols, g_valid & jnp.take(load_row, src)
 
 
 def fetch_load_set(
@@ -93,7 +95,7 @@ def gather_load_set_ring(
     e.g. range partitioning of a graph with ring/band locality. The engine
     checks applicability host-side before selecting this path.
     """
-    S = axis_size(axis_name)
+    S = lax.axis_size(axis_name)
     idx = lax.axis_index(axis_name)
     h = min(max_dist, (S - 1) // 2)
     outs_c = [cols]

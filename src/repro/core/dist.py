@@ -30,16 +30,21 @@ import numpy as np
 from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from repro.compat import shard_map
+from jax import shard_map
 from repro.core import join as join_lib
-from repro.core.backend import Kernels, resolve_kernels
+from repro.core.backend import Kernels, n_words, resolve_kernels
 from repro.core.cache import ExecutableCache
 from repro.core.collectives import fetch_load_set, or_allreduce
 from repro.core.deprecation import warn_direct_construction
-from repro.core.match import Bindings, ShardGraph, match_stwig_shard
+from repro.core.match import (
+    Bindings,
+    ShardGraph,
+    emit_stwig_round,
+    expand_stwig_shard,
+)
 from repro.core.plan import QueryPlan, STwigSpec, caps_from_plan, make_plan
 from repro.core.query import QueryGraph
-from repro.core.result import MatchPage, MatchResult, MatchStats
+from repro.core.result import MatchPage, MatchResult, MatchStats, first_k_met
 from repro.core.stream import stream_blocks
 from repro.runtime.chaos import ShardFaultError
 from repro.runtime.resilience import (
@@ -129,30 +134,62 @@ class DistributedMatcher:
         self.join_block_calls = 0
 
     # ------------------------------------------------------- jitted steps
-    def _match_step(self, spec: STwigSpec):
-        return self.cache.get(
-            ("dist_match", spec, self.kernels.name),
-            lambda: self._build_match_step(spec),
+    def _match_fns(self, spec: STwigSpec):
+        """The two shard_map'd halves of one STwig, as on the local engine:
+        expand once (each shard's expansion stays on its device), then
+        emit per round of roots. Both are "dist_match" entry points."""
+        name = self.kernels.name
+        return (
+            self.cache.get(
+                ("dist_match", spec, name, "expand"),
+                lambda: self._build_expand_step(spec),
+            ),
+            self.cache.get(
+                ("dist_match", spec, name, "emit"),
+                lambda: self._build_emit_step(spec),
+            ),
         )
 
-    def _build_match_step(self, spec: STwigSpec):
+    def _build_expand_step(self, spec: STwigSpec):
         gspecs = (P(AXIS),) * 6 + (P(),)
         kern = self.kernels
 
-        def body(tree, bind_words, round_idx):
+        def body(tree, bind_words):
             g = _local_shard_graph(tree)
-            table, contrib = match_stwig_shard(
-                g, Bindings(bind_words), spec, round_idx, kernels=kern
+            exp = expand_stwig_shard(g, Bindings(bind_words), spec, kern)
+            n_roots_max = lax.pmax(exp.n_roots, AXIS)
+            return jax.tree.map(lambda x: x[None], exp), n_roots_max
+
+        return jax.jit(
+            shard_map(
+                body,
+                mesh=self.mesh,
+                in_specs=(gspecs, P()),
+                out_specs=(P(AXIS), P()),
+                # Pallas kernels declare outputs without a varying axis,
+                # which shard_map's static VMA check rejects
+                check_vma=False,
+            )
+        )
+
+    def _build_emit_step(self, spec: STwigSpec):
+        gspecs = (P(AXIS),) * 6 + (P(),)
+        kern = self.kernels
+        n_w = n_words(self.pg.n_total + 1)
+
+        def body(tree, exp, round_idx):
+            g = _local_shard_graph(tree)
+            exp = jax.tree.map(lambda x: x[0], exp)
+            table, contrib = emit_stwig_round(
+                g, exp, spec, round_idx, n_w, kernels=kern
             )
             contrib_w = or_allreduce(contrib.words, AXIS)
-            n_roots_max = lax.pmax(table.n_roots, AXIS)
             overflow_any = lax.pmax(table.overflow.astype(jnp.int32), AXIS) > 0
             return (
                 table.cols[None],
                 table.valid[None],
                 table.n_rows[None],
                 contrib_w,
-                n_roots_max,
                 overflow_any,
             )
 
@@ -160,8 +197,8 @@ class DistributedMatcher:
             shard_map(
                 body,
                 mesh=self.mesh,
-                in_specs=(gspecs, P(), P()),
-                out_specs=(P(AXIS), P(AXIS), P(AXIS), P(), P(), P()),
+                in_specs=(gspecs, P(AXIS), P()),
+                out_specs=(P(AXIS), P(AXIS), P(AXIS), P(), P()),
                 # the OR-allreduce butterfly (ppermute) produces replicated
                 # values shard_map's static VMA check cannot infer
                 check_vma=False,
@@ -605,20 +642,25 @@ class DistributedMatcher:
         overflow = False
         all_cols, all_valids = [], []
         for spec in plan.specs:
-            fn = self._match_step(spec)
+            expand, emit = self._match_fns(spec)
+            exp, n_roots_max = expand(self._g.tree(), bind)
             round_cols, round_valids = [], []
             contrib = None
             n_rows_tot = 0
-            n_roots_max = 0
             r = 0
             while True:
-                cols, valid, n_rows, cw, n_roots_max, ovf = fn(
-                    self._g.tree(), bind, jnp.int32(r)
+                cols, valid, n_rows, cw, ovf = emit(
+                    self._g.tree(), exp, jnp.int32(r)
                 )
-                round_cols.append(cols)
-                round_valids.append(valid)
+                # each shard's valid rows lead its table: keep the rows
+                # the fullest shard needs, and no empty round past the first
+                n_rows = np.asarray(n_rows)
+                if n_rows.any() or not round_cols:
+                    keep = join_lib.fitted_cap(int(n_rows.max()), spec.rows_cap)
+                    round_cols.append(cols[:, :keep])
+                    round_valids.append(valid[:, :keep])
                 contrib = cw if contrib is None else jnp.bitwise_or(contrib, cw)
-                n_rows_tot += int(jnp.sum(n_rows))
+                n_rows_tot += int(n_rows.sum())
                 overflow |= bool(ovf)
                 r += 1
                 if r * spec.root_cap >= int(n_roots_max):
@@ -695,6 +737,11 @@ class DistributedMatcher:
         order = tuple(
             join_lib.select_join_order(list(schemas), stats.stwig_rows)
         )
+        with stage(stats, "join"):
+            all_cols, all_valids = zip(*(
+                join_lib.compact_stacked(c, v)
+                for c, v in zip(all_cols, all_valids)
+            ))
         caps = tuple(int(c.shape[1]) for c in all_cols)
         ring_radii = self.ring_radii_for(load) if use_ring else None
         with stage(stats, "join"):
@@ -726,7 +773,7 @@ class DistributedMatcher:
         return MatchResult(
             rows=rows_old,
             n_matches=int(rows_old.shape[0]),
-            complete=not (overflow or fault),
+            complete=not fault and (first_k_met(plan, rows_old) or not overflow),
             stats=stats,
         )
 

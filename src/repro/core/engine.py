@@ -22,7 +22,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core import join as join_lib
-from repro.core.backend import Kernels, resolve_kernels
+from repro.core.backend import Kernels, n_words, resolve_kernels
 from repro.core.cache import ExecutableCache
 from repro.core.deprecation import warn_direct_construction
 from repro.core.match import (
@@ -30,11 +30,12 @@ from repro.core.match import (
     ShardGraph,
     STwigTable,
     apply_binding_update,
-    match_stwig_shard,
+    emit_stwig_round,
+    expand_stwig_shard,
 )
 from repro.core.plan import QueryPlan, STwigSpec, caps_from_plan, make_plan
 from repro.core.query import QueryGraph
-from repro.core.result import MatchPage, MatchResult, MatchStats
+from repro.core.result import MatchPage, MatchResult, MatchStats, first_k_met
 from repro.core.stream import stream_blocks
 from repro.graphstore.partition import PartitionedGraph
 from repro.runtime.resilience import RetryPolicy, adaptive_run, grow_caps, stage
@@ -66,6 +67,19 @@ def _concat_tables(tables: list[STwigTable]) -> join_lib.JoinTable:
         jnp.logical_or, [t.overflow for t in tables], jnp.bool_(False)
     )
     return join_lib.JoinTable(cols=cols, valid=valid, n_rows=n_rows, overflow=overflow)
+
+
+def stwig_step_fns(spec: STwigSpec, kernels: Kernels, n_words_: int):
+    """The two halves of one STwig step as the local engine jits them:
+    ``expand(g, bind)`` once per STwig, then ``emit(g, exp, round_idx=r)``
+    per round of roots (a round then costs its roots, not another pass
+    over every edge)."""
+    return (
+        functools.partial(expand_stwig_shard, spec=spec, kernels=kernels),
+        functools.partial(
+            emit_stwig_round, spec=spec, n_words_=n_words_, kernels=kernels
+        ),
+    )
 
 
 @dataclasses.dataclass(eq=False)
@@ -126,13 +140,17 @@ class SubgraphMatcher:
         )
 
     # -------------------------------------------------- cached executables
-    def _match_fn(self, spec: STwigSpec):
+    def _match_fns(self, spec: STwigSpec):
+        """The two jitted halves of one STwig (`stwig_step_fns`). Both are
+        "match" entry points for the cost budgets."""
         kern = self.kernels
-        return self.cache.get(
-            ("match", spec, kern.name),
-            lambda: jax.jit(
-                functools.partial(match_stwig_shard, spec=spec, kernels=kern)
-            ),
+        n_w = n_words(self.pg.n_total + 1)
+        return tuple(
+            self.cache.get(
+                ("match", spec, kern.name, half, n_w),
+                lambda i=i: jax.jit(stwig_step_fns(spec, kern, n_w)[i]),
+            )
+            for i, half in enumerate(("expand", "emit"))
         )
 
     def _join_fn(
@@ -298,16 +316,25 @@ class SubgraphMatcher:
         schemas: list[join_lib.Schema] = []
         overflow = False
         for spec in plan.specs:
-            fn = self._match_fn(spec)
+            expand, emit = self._match_fns(spec)
+            exp = expand(self.g, bind)
+            n_roots = int(exp.n_roots)
             round_tables: list[STwigTable] = []
             contrib = None
             r = 0
             while True:
-                table, c = fn(self.g, bind, round_idx=jnp.int32(r))
-                round_tables.append(table)
+                table, c = emit(self.g, exp, round_idx=jnp.int32(r))
+                # a round's valid rows lead its table: keep only those
+                # (rounds of a hub-heavy STwig are mostly empty, and
+                # rounds x rows_cap would not fit the device)
+                n = int(table.n_rows)
+                if n or not round_tables:
+                    keep = join_lib.fitted_cap(n, spec.rows_cap)
+                    round_tables.append(table._replace(
+                        cols=table.cols[:keep], valid=table.valid[:keep]
+                    ))
                 cw = c.words
                 contrib = cw if contrib is None else jnp.bitwise_or(contrib, cw)
-                n_roots = int(table.n_roots)
                 r += 1
                 if r * spec.root_cap >= n_roots:
                     break
@@ -362,6 +389,7 @@ class SubgraphMatcher:
 
         # ---- join phase ----------------------------------------------------
         with stage(stats, "join"):
+            tables = [join_lib.compact_table(t) for t in tables]
             order = join_lib.select_join_order(schemas, stats.stwig_rows)
             acc, acc_schema = tables[order[0]], schemas[order[0]]
             for idx in order[1:]:
@@ -384,6 +412,6 @@ class SubgraphMatcher:
         return MatchResult(
             rows=rows_old,
             n_matches=int(rows_old.shape[0]),
-            complete=not overflow,
+            complete=first_k_met(plan, rows_old) or not overflow,
             stats=stats,
         )

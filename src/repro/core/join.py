@@ -26,9 +26,9 @@ from typing import NamedTuple
 
 import jax.numpy as jnp
 import numpy as np
-from jax import lax
 
 from repro.core.backend import Kernels, get_kernels
+from repro.kernels.scan import compact_rows, cumsum, radix_argsort
 
 
 class JoinTable(NamedTuple):
@@ -102,26 +102,26 @@ def sort_merge_join(
     BIG = jnp.uint32(0xFFFFFFFF)
     key_a = jnp.where(a.valid, _combine_keys(a.cols, pos_a), BIG)
     key_b = _combine_keys(b.cols, pos_b)
-    # sort with an int32 payload rather than argsort: argsort's permutation
-    # is int64 under x64 and would widen every downstream gather
-    iota = jnp.arange(key_a.shape[0], dtype=jnp.int32)
-    ka, order = lax.sort((key_a, iota), num_keys=1)
+    # an int32 permutation (argsort's is int64 under x64 and would widen
+    # every downstream gather), from a radix sort the TPU compiles quickly
+    ka, order = radix_argsort(key_a)
     a_valid_s = a.valid[order]
 
     # build-side duplicate-run overflow detection
     run_start = jnp.concatenate(
         [jnp.ones((1,), bool), ka[1:] != ka[:-1]]
     ) | ~a_valid_s
-    run_id = jnp.cumsum(run_start.astype(jnp.int32)) - 1
+    run_id = cumsum(run_start) - 1
     run_len = jnp.zeros(ka.shape[0], jnp.int32).at[run_id].add(1)
     dup_overflow = jnp.max(jnp.where(a_valid_s, run_len[run_id], np.int32(0))) > dup_cap
 
-    # windowed probe with exact-key verification — one fused backend op
+    # windowed probe with exact-key verification — one fused backend op.
+    # Columns are handled one 1-D int32 array at a time: a narrow
+    # (rows, width) array is padded to 128 lanes on the TPU, which at the
+    # flattened probe width (capB * dup_cap rows) costs gigabytes
     W = dup_cap
-    key_pos_a = jnp.asarray(pos_a, jnp.int32)
-    key_pos_b = jnp.asarray(pos_b, jnp.int32)
-    a_keys_s = a.cols[order][:, key_pos_a]     # (na, nk) sorted key columns
-    b_keys = b.cols[:, key_pos_b]              # (nb, nk)
+    a_keys_s = jnp.stack([jnp.take(a.cols[:, p], order) for p in pos_a], axis=1)
+    b_keys = jnp.stack([b.cols[:, p] for p in pos_b], axis=1)
     hit, probe_c = kern.hash_join_probe(
         ka, a_keys_s, a_valid_s, key_b, b_keys, b.valid, dup_cap=W
     )
@@ -132,16 +132,16 @@ def sort_merge_join(
         i for i, q in enumerate(schema_b.qnodes) if q not in schema_a.qnodes
     )
     nb = b.cols.shape[0]
-    flat_hit = hit.reshape(-1)
+    # the flattened mask stays int32 0/1: a bool array that crosses a
+    # reshape at this width takes the TPU compiler 15 s, int32 under one
+    flat_hit = hit.astype(jnp.int32).reshape(-1)
     a_rows_f = a_rows.reshape(-1)
-    b_rows_f = jnp.broadcast_to(
-        jnp.arange(nb, dtype=jnp.int32)[:, None], (nb, W)
-    ).reshape(-1)
-    merged_cols = jnp.concatenate(
-        [a.cols[a_rows_f]]
-        + [jnp.take(b.cols[:, p], b_rows_f)[:, None] for p in extra_pos_b],
-        axis=1,
-    )  # (nb*W, w_merged)
+    merged_cols = [
+        jnp.take(a.cols[:, p], a_rows_f) for p in range(a.cols.shape[1])
+    ] + [
+        jnp.broadcast_to(b.cols[:, p][:, None], (nb, W)).reshape(-1)
+        for p in extra_pos_b
+    ]
 
     # isomorphism (injectivity) filter on equal-label column pairs
     labs = merged_schema.qlabels
@@ -149,15 +149,13 @@ def sort_merge_join(
     for i in range(wm):
         for j in range(i + 1, wm):
             if labs[i] == labs[j]:
-                flat_hit &= merged_cols[:, i] != merged_cols[:, j]
+                flat_hit = jnp.where(
+                    merged_cols[i] != merged_cols[j], flat_hit, np.int32(0)
+                )
 
     n_rows = jnp.sum(flat_hit, dtype=jnp.int32)
-    rk = jnp.cumsum(flat_hit.astype(jnp.int32)) - flat_hit.astype(jnp.int32)
-    out_pos = jnp.where(flat_hit, rk, np.int32(out_cap))
     ghost = jnp.max(a.cols)  # any value; rows are masked by `valid`
-    cols = jnp.full((out_cap, wm), ghost, dtype=jnp.int32)
-    cols = cols.at[out_pos].set(merged_cols, mode="drop")
-    valid = jnp.zeros((out_cap,), bool).at[out_pos].set(flat_hit, mode="drop")
+    cols, valid = compact_rows(merged_cols, flat_hit, out_cap, ghost)
     overflow = (n_rows > out_cap) | dup_overflow | a.overflow | b.overflow
 
     return (
@@ -190,38 +188,72 @@ def block_table(table: JoinTable, lo, block_rows: int) -> JoinTable:
     )
 
 
+def fitted_cap(n_rows: int, cap: int, min_cap: int = 64) -> int:
+    """The power of two, at least ``min_cap`` and at most ``cap``, that
+    holds ``n_rows`` rows: the capacity a sparse table is cut down to."""
+    new_cap = min_cap
+    while new_cap < n_rows:
+        new_cap *= 2
+    return min(new_cap, cap)
+
+
+def _compact_host(cols: np.ndarray, valid: np.ndarray, min_cap: int):
+    """Numpy core of the compactions below, over ``cols (S, cap, w)`` and
+    ``valid (S, cap)``; None when the capacity would not shrink."""
+    counts = valid.sum(axis=1)
+    new_cap = fitted_cap(int(counts.max(initial=0)), cols.shape[1], min_cap)
+    if new_cap >= cols.shape[1]:
+        return None
+    out_cols = np.zeros((cols.shape[0], new_cap, cols.shape[2]), cols.dtype)
+    out_valid = np.zeros((cols.shape[0], new_cap), bool)
+    for s in range(cols.shape[0]):
+        keep = np.nonzero(valid[s])[0]
+        out_cols[s, : len(keep)] = cols[s, keep]
+        out_valid[s, : len(keep)] = True
+    return out_cols, out_valid
+
+
 def compact_table(table: JoinTable, min_cap: int = 64) -> JoinTable:
     """Squeeze a table's valid rows to the front and shrink its capacity to
     the smallest power of two that holds them all (host-side; costs one
     device round-trip, so callers must already be off the async path).
 
-    STwig tables are allocated at worst-case capacity but are usually
-    sparse, and the probe side of every join pays O(cap × dup_cap) in
-    window expansion and scatter regardless of how many rows are real.
-    The streaming path re-probes the same tables once per block, so the
-    setup step compacts them once and every block join gets cheaper.
-    Lossless by construction: the compact capacity covers every valid row
-    and the exact-count/overflow flags are carried over unchanged. The
-    one-shot path stays fully on device and keeps full-capacity tables.
+    STwig tables are allocated at worst-case capacity (``rows_cap`` per
+    exploration round) but are usually sparse, and a join pays
+    O(cap × dup_cap) in window expansion and scatter regardless of how many
+    rows are real: at full graph width an uncompacted multi-round table
+    makes a join that does not fit a 16 GB chip. Both the one-shot and the
+    streaming paths compact before joining. Lossless by construction: the
+    compact capacity covers every valid row, rows keep their order, and
+    the exact-count/overflow flags are carried over unchanged.
     """
-    cols = np.asarray(table.cols)
-    valid = np.asarray(table.valid)
-    keep = np.nonzero(valid)[0]
-    cap = int(cols.shape[0])
-    new_cap = min_cap
-    while new_cap < len(keep):
-        new_cap *= 2
-    if new_cap >= cap:
+    out = _compact_host(
+        np.asarray(table.cols)[None], np.asarray(table.valid)[None], min_cap
+    )
+    if out is None:
         return table
-    out_cols = np.zeros((new_cap, cols.shape[1]), cols.dtype)
-    out_cols[: len(keep)] = cols[keep]
-    out_valid = np.zeros((new_cap,), bool)
-    out_valid[: len(keep)] = True
     return JoinTable(
-        cols=jnp.asarray(out_cols),
-        valid=jnp.asarray(out_valid),
+        cols=jnp.asarray(out[0][0]),
+        valid=jnp.asarray(out[1][0]),
         n_rows=table.n_rows,
         overflow=table.overflow,
+    )
+
+
+def compact_stacked(cols, valid, min_cap: int = 64):
+    """`compact_table` for per-shard stacked tables ``cols (S, cap, w)`` /
+    ``valid (S, cap)``: every shard's valid rows move to the front, and the
+    common capacity shrinks to the power of two that holds the fullest
+    shard. The results keep the inputs' shardings."""
+    import jax
+
+    out = _compact_host(np.asarray(cols), np.asarray(valid), min_cap)
+    if out is None:
+        return cols, valid
+    # cols and valid share the leading shard axis, so one sharding serves
+    return (
+        jax.device_put(out[0], cols.sharding),
+        jax.device_put(out[1], cols.sharding),
     )
 
 

@@ -28,6 +28,7 @@ import numpy as np
 
 from repro.core.backend import Kernels, get_kernels, n_words
 from repro.core.plan import STwigSpec
+from repro.kernels.scan import compact_rows, exclusive_cumsum
 
 
 class ShardGraph(NamedTuple):
@@ -75,32 +76,33 @@ class Bindings(NamedTuple):
         return Bindings(jnp.zeros((n_qnodes, n_words(n_bits)), jnp.uint32))
 
 
-def _exclusive_cumsum(m: jnp.ndarray) -> jnp.ndarray:
-    c = jnp.cumsum(m.astype(jnp.int32))
-    return c - m.astype(jnp.int32)
+class STwigExpansion(NamedTuple):
+    """Steps 1-3 of one STwig on one shard: what every round of root chunks
+    shares. Computed once per STwig; each round then only emits its chunk."""
+
+    cand: jnp.ndarray       # (k, cap+1, C) int32 candidate ids, ghost-padded
+    cnt: jnp.ndarray        # (k, cap+1) int32 exact counts, pad row zero
+    root_mask: jnp.ndarray  # (cap,) bool roots with every required child
+    rank: jnp.ndarray       # (cap,) int32 exclusive rank among those roots
+    n_roots: jnp.ndarray    # () int32
+    overflow: jnp.ndarray   # () bool — some count exceeds child_cap
 
 
-def match_stwig_shard(
+def expand_stwig_shard(
     g: ShardGraph,
     bind: Bindings,
     spec: STwigSpec,
-    round_idx: jnp.ndarray,
     kernels: Kernels | None = None,
-) -> tuple[STwigTable, Bindings]:
-    """Match one STwig on one shard (round ``round_idx`` of root chunks).
-
-    Returns the local match table and *this shard's contribution* to the new
-    bindings for the STwig's query nodes (caller OR-reduces across shards,
-    then replaces rows of ``bind``). ``kernels`` selects the backend for the
-    dense inner ops (default: the jnp reference set) and must be bound
-    statically (e.g. via ``functools.partial``) before ``jit``.
-    """
+) -> STwigExpansion:
+    """Root candidates and per-root child candidate lists of one STwig on
+    one shard (steps 1-3, one edge-parallel pass over the shard's CSR).
+    ``kernels`` selects the backend for the dense inner ops (default: the
+    jnp reference set) and must be bound statically before ``jit``."""
     kern = kernels if kernels is not None else get_kernels("jnp")
     cap, edge_cap = g.cap, g.edge_cap
     n_total = g.n_total
     k = spec.n_children
-    C, R = spec.child_cap, spec.root_cap
-    W = bind.words.shape[1]
+    C = spec.child_cap
 
     node_slot = jnp.arange(cap, dtype=jnp.int32)
     gid = g.shard_id.astype(jnp.int32) * cap + node_slot
@@ -122,38 +124,54 @@ def match_stwig_shard(
     indptr_pad = jnp.concatenate(
         [g.indptr, jnp.full((1,), np.int32(edge_cap), jnp.int32)]
     )
-
-    if k > 0:
-        words_k = jnp.stack([bind.words[q] for q in spec.child_qnodes])
-        cand_k, cnt_k = kern.stwig_expand(
-            words_k,
-            g.indices,
-            dst_labels,
-            indptr_pad,
-            root_ok_e,
-            child_labels=spec.child_labels,
-            child_bound=spec.child_bound,
-            child_cap=C,
-            cap=cap,
-            n_total=n_total,
-        )
-        # per child: (cap+1, C) ghost-padded candidate ids / (cap,) counts
-        cand = [cand_k[i] for i in range(k)]
-        cnt = [cnt_k[i] for i in range(k)]
-    else:  # pragma: no cover — STwigs always have ≥1 child
-        cand, cnt = [], []
+    words_k = jnp.stack([bind.words[q] for q in spec.child_qnodes])
+    cand, cnt = kern.stwig_expand(
+        words_k,
+        g.indices,
+        dst_labels,
+        indptr_pad,
+        root_ok_e,
+        child_labels=spec.child_labels,
+        child_bound=spec.child_bound,
+        child_cap=C,
+        cap=cap,
+        n_total=n_total,
+    )  # (k, cap+1, C) ghost-padded candidate ids / (k, cap) counts
 
     # ---- prune roots missing required children ----------------------------
     for i in range(k):
         root_mask &= cnt[i] >= spec.child_need[i]
+    return STwigExpansion(
+        cand=cand,
+        cnt=jnp.concatenate([cnt, jnp.zeros((k, 1), jnp.int32)], axis=1),
+        root_mask=root_mask,
+        rank=exclusive_cumsum(root_mask),
+        n_roots=jnp.sum(root_mask, dtype=jnp.int32),
+        overflow=jnp.max(cnt) > C,
+    )
 
-    n_roots = jnp.sum(root_mask, dtype=jnp.int32)
+
+def emit_stwig_round(
+    g: ShardGraph,
+    exp: STwigExpansion,
+    spec: STwigSpec,
+    round_idx: jnp.ndarray,
+    n_words_: int,
+    kernels: Kernels | None = None,
+) -> tuple[STwigTable, Bindings]:
+    """Round ``round_idx`` of one STwig's root chunks (steps 4-5): the match
+    table of ``spec.root_cap`` roots and *this shard's contribution* to the
+    bindings of the STwig's query nodes (``n_words_`` words each)."""
+    kern = kernels if kernels is not None else get_kernels("jnp")
+    cap, n_total = g.cap, g.n_total
+    k = spec.n_children
+    C, R = spec.child_cap, spec.root_cap
+    node_slot = jnp.arange(cap, dtype=jnp.int32)
 
     # ---- select this round's chunk of roots --------------------------------
-    rank = _exclusive_cumsum(root_mask)
     lo = round_idx.astype(jnp.int32) * R
-    sel = root_mask & (rank >= lo) & (rank < lo + R)
-    chunk_pos = jnp.where(sel, rank - lo, np.int32(R))
+    sel = exp.root_mask & (exp.rank >= lo) & (exp.rank < lo + R)
+    chunk_pos = jnp.where(sel, exp.rank - lo, np.int32(R))
     roots_sel = jnp.full((R,), cap, dtype=jnp.int32)
     roots_sel = roots_sel.at[chunk_pos].set(node_slot, mode="drop")
     root_live = roots_sel < cap
@@ -161,59 +179,42 @@ def match_stwig_shard(
         root_live, g.shard_id.astype(jnp.int32) * cap + roots_sel, np.int32(n_total)
     )
 
-    cand_sel = [jnp.take(cand[i], roots_sel, axis=0, mode="clip") for i in range(k)]
-    cnt_pad = [jnp.concatenate([cnt[i], jnp.zeros((1,), jnp.int32)]) for i in range(k)]
-    cnt_sel = [jnp.take(cnt_pad[i], roots_sel, mode="clip") for i in range(k)]
+    cand_sel = [jnp.take(exp.cand[i], roots_sel, axis=0, mode="clip") for i in range(k)]
+    cnt_sel = [jnp.take(exp.cnt[i], roots_sel, mode="clip") for i in range(k)]
 
     # ---- step 4: masked cross-product emission -----------------------------
-    if k > 0:
-        grid = jnp.indices((C,) * k, dtype=jnp.int32).reshape(k, -1)  # (k, P)
-        P = grid.shape[1]
-        child_vals = [
-            jnp.take_along_axis(cand_sel[i], grid[i][None, :], axis=1)
-            for i in range(k)
-        ]  # each (R, P)
-        ok = root_live[:, None] & jnp.ones((R, P), bool)
-        for i in range(k):
-            ok &= grid[i][None, :] < cnt_sel[i][:, None]
-        for i, j in spec.same_label_child_pairs:
-            ok &= grid[i][None, :] != grid[j][None, :]
-        for i in spec.root_label_child_positions:
-            ok &= child_vals[i] != root_gid[:, None]
-        flat_ok = ok.reshape(-1)
-        rows = jnp.stack(
-            [jnp.broadcast_to(root_gid[:, None], (R, P)).reshape(-1)]
-            + [v.reshape(-1) for v in child_vals],
-            axis=1,
-        )  # (R*P, width)
-    else:  # pragma: no cover — STwigs always have ≥1 child
-        flat_ok = root_live
-        rows = root_gid[:, None]
+    grid = jnp.indices((C,) * k, dtype=jnp.int32).reshape(k, -1)  # (k, P)
+    P = grid.shape[1]
+    child_vals = [
+        jnp.take_along_axis(cand_sel[i], grid[i][None, :], axis=1)
+        for i in range(k)
+    ]  # each (R, P)
+    ok = root_live[:, None] & jnp.ones((R, P), bool)
+    for i in range(k):
+        ok &= grid[i][None, :] < cnt_sel[i][:, None]
+    for i, j in spec.same_label_child_pairs:
+        ok &= grid[i][None, :] != grid[j][None, :]
+    for i in spec.root_label_child_positions:
+        ok &= child_vals[i] != root_gid[:, None]
+    # int32 0/1: a bool array that crosses a reshape is slow to compile on
+    # the TPU
+    flat_ok = ok.astype(jnp.int32).reshape(-1)
+    rows = [jnp.broadcast_to(root_gid[:, None], (R, P)).reshape(-1)] + [
+        v.reshape(-1) for v in child_vals
+    ]  # width columns of (R*P,)
 
     n_rows = jnp.sum(flat_ok, dtype=jnp.int32)
-    rk = _exclusive_cumsum(flat_ok)
-    out_pos = jnp.where(flat_ok, rk, np.int32(spec.rows_cap))
-    cols = jnp.full((spec.rows_cap, spec.width), n_total, dtype=jnp.int32)
-    cols = cols.at[out_pos].set(rows, mode="drop")
-    valid = jnp.zeros((spec.rows_cap,), bool).at[out_pos].set(
-        flat_ok, mode="drop"
-    )
-
-    overflow = (n_rows > spec.rows_cap) | jnp.any(
-        jnp.stack([jnp.max(cnt[i]) > C for i in range(k)])
-        if k
-        else jnp.zeros((1,), bool)
-    )
+    cols, valid = compact_rows(rows, flat_ok, spec.rows_cap, np.int32(n_total))
+    overflow = (n_rows > spec.rows_cap) | exp.overflow
 
     # ---- step 5: binding contributions (scatter-OR) ------------------------
-    new_words = []
-    for pos_, _q in enumerate(spec.qnodes):
-        col = cols[:, pos_]
-        new_words.append(kern.bitset_build(col, valid, W))
-    contrib = jnp.stack(new_words)  # (width, W)
+    contrib = jnp.stack(
+        [kern.bitset_build(cols[:, p], valid, n_words_) for p in range(spec.width)]
+    )  # (width, W)
 
     table = STwigTable(
-        cols=cols, valid=valid, n_rows=n_rows, n_roots=n_roots, overflow=overflow
+        cols=cols, valid=valid, n_rows=n_rows, n_roots=exp.n_roots,
+        overflow=overflow,
     )
     return table, Bindings(contrib)
 

@@ -72,11 +72,20 @@ class MatchStats:
         return getattr(self, key, default)
 
 
+def first_k_met(plan, rows: np.ndarray) -> bool:
+    """True when a first-K plan (``max_matches`` K > 0) has its K rows. Its
+    answer is then whole even if a capacity overflowed on the way: an
+    overflow only drops matches, and the rows found are K real ones."""
+    return bool(plan.max_matches) and rows.shape[0] >= plan.max_matches
+
+
 @dataclasses.dataclass
 class MatchResult:
     rows: np.ndarray          # (n_matches, n_qnodes) ORIGINAL node ids
     n_matches: int
-    complete: bool            # False if any capacity overflowed (partial set)
+    # False if a capacity overflowed (a partial set) before a first-K
+    # query found its K rows
+    complete: bool
     stats: MatchStats
 
     @property

@@ -262,6 +262,7 @@ def run_cell(
             hbm_bytes=cost["bytes"],
             collective_bytes=cost["coll"],
             n_chips=n_chips,
+            peaks=rl.PEAKS["TPU v5 lite"],  # dry runs target a v5e mesh
             model_flops=_model_flops(entry, cell, cfg),
         )
         coll = rl.CollectiveStats(cost["coll_by_kind"], cost["coll_count"])

@@ -61,6 +61,15 @@ def rmat(
     )
 
 
+def patents_like(scale: float = 1.0, seed: int = 0) -> Graph:
+    """US-Patents-shaped R-MAT (the paper's §6 real dataset): 3,774,768
+    nodes, 16,522,438 edges before symmetrising, 418 labels; ``scale``
+    shrinks the node and edge counts together."""
+    n = max(int(3_774_768 * scale), 1000)
+    m = max(int(16_522_438 * scale), 4000)
+    return rmat(n, m, 418, seed=seed)
+
+
 def erdos_renyi(
     n_nodes: int, n_edges: int, n_labels: int, *, seed: int = 0
 ) -> Graph:

@@ -23,35 +23,58 @@ from jax.experimental import pallas as pl
 from repro.kernels.bitset.ref import WORD_BITS, lookup_reference
 
 
-# ----------------------------------------------------------------- unpack
-def _unpack_kernel(w_ref, o_ref, *, bw: int):
-    w = w_ref[...]  # (BW,)
-    shifts = jax.lax.broadcasted_iota(jnp.uint32, (bw, WORD_BITS), 1)
-    bits = (w[:, None] >> shifts) & jnp.uint32(1)
-    o_ref[...] = bits.astype(jnp.bool_).reshape(bw * WORD_BITS)
+# ------------------------------------------------------- range ops (2-D)
+# Mosaic lays a block out as (sublane, lane) tiles: a 1-D bool block that is
+# reshaped between words and bits is refused ("unsupported shape cast"), and
+# so is a lane reduction over uint32. The range kernels therefore work on
+# int32 2-D blocks (pack: ``(W, 32)`` bit rows to a ``(W, 1)`` word column;
+# unpack: four words per row to 128-lane bit rows); the uint32/bool views
+# are taken outside the kernel, and rows are padded to whole ``bw``-row
+# blocks.
+_LANES = 128
+
+
+def _blocks(W: int, bw: int) -> tuple[int, int]:
+    bw = min(bw, -(-W // 8) * 8)
+    return bw, -(-W // bw) * bw
+
+
+def _unpack_kernel(w_ref, o_ref):
+    # four words per 128-lane row: lane j holds bit j % 32 of word j // 32,
+    # so the row-major output is already the flat bit order
+    lane = jax.lax.broadcasted_iota(jnp.int32, o_ref.shape, 1)
+    w = w_ref[...]
+    bits = jnp.zeros(o_ref.shape, jnp.int32)
+    for c in range(_LANES // WORD_BITS):
+        own = jax.lax.shift_right_logical(w[:, c : c + 1], lane % WORD_BITS) & 1
+        bits = jnp.where(lane // WORD_BITS == c, own, bits)
+    o_ref[...] = bits
 
 
 def bitset_unpack(words: jnp.ndarray, *, bw: int = 512, interpret: bool = False):
     """(W,) uint32 → (W*32,) bool, tiled over word blocks."""
     W = words.shape[0]
-    bw = min(bw, W)
-    while W % bw:
-        bw //= 2
-    return pl.pallas_call(
-        functools.partial(_unpack_kernel, bw=bw),
-        grid=(W // bw,),
-        in_specs=[pl.BlockSpec((bw,), lambda i: (i,))],
-        out_specs=pl.BlockSpec((bw * WORD_BITS,), lambda i: (i,)),
-        out_shape=jax.ShapeDtypeStruct((W * WORD_BITS,), jnp.bool_),
+    per_row = _LANES // WORD_BITS
+    rows = -(-W // per_row)
+    bw, rp = _blocks(rows, bw)
+    w = jax.lax.bitcast_convert_type(words, jnp.int32)
+    w = jnp.pad(w, (0, rp * per_row - W)).reshape(rp, per_row)
+    bits = pl.pallas_call(
+        _unpack_kernel,
+        grid=(rp // bw,),
+        in_specs=[pl.BlockSpec((bw, per_row), lambda i: (i, 0))],
+        out_specs=pl.BlockSpec((bw, _LANES), lambda i: (i, 0)),
+        out_shape=jax.ShapeDtypeStruct((rp, _LANES), jnp.int32),
         interpret=interpret,
-    )(words)
+    )(w)
+    # (rows, 128) flattens without a relayout; a (W, 32) view would not
+    return bits.reshape(rp * _LANES)[: W * WORD_BITS] != 0
 
 
-# ------------------------------------------------------------------- pack
-def _pack_kernel(m_ref, o_ref, *, bw: int):
-    bits = m_ref[...].reshape(bw, WORD_BITS).astype(jnp.uint32)
-    shifts = jax.lax.broadcasted_iota(jnp.uint32, (bw, WORD_BITS), 1)
-    o_ref[...] = jnp.sum(bits << shifts, axis=1, dtype=jnp.uint32)
+def _pack_kernel(m_ref, o_ref):
+    shifts = jax.lax.broadcasted_iota(jnp.int32, m_ref.shape, 1)
+    # distinct bits, so the int32 sum is their OR (bit 31 wraps to the sign)
+    o_ref[...] = jnp.sum(m_ref[...] << shifts, axis=1, keepdims=True)
 
 
 def bitset_pack(mask: jnp.ndarray, *, bw: int = 512, interpret: bool = False):
@@ -59,17 +82,18 @@ def bitset_pack(mask: jnp.ndarray, *, bw: int = 512, interpret: bool = False):
     n = mask.shape[0]
     assert n % WORD_BITS == 0
     W = n // WORD_BITS
-    bw = min(bw, W)
-    while W % bw:
-        bw //= 2
-    return pl.pallas_call(
-        functools.partial(_pack_kernel, bw=bw),
-        grid=(W // bw,),
-        in_specs=[pl.BlockSpec((bw * WORD_BITS,), lambda i: (i,))],
-        out_specs=pl.BlockSpec((bw,), lambda i: (i,)),
-        out_shape=jax.ShapeDtypeStruct((W,), jnp.uint32),
+    bw, wp = _blocks(W, bw)
+    m = mask.astype(jnp.int32).reshape(W, WORD_BITS)
+    m = jnp.pad(m, ((0, wp - W), (0, 0)))
+    words = pl.pallas_call(
+        _pack_kernel,
+        grid=(wp // bw,),
+        in_specs=[pl.BlockSpec((bw, WORD_BITS), lambda i: (i, 0))],
+        out_specs=pl.BlockSpec((bw, 1), lambda i: (i, 0)),
+        out_shape=jax.ShapeDtypeStruct((wp, 1), jnp.int32),
         interpret=interpret,
-    )(mask)
+    )(m)
+    return jax.lax.bitcast_convert_type(words[:W, 0], jnp.uint32)
 
 
 # ----------------------------------------------------------------- lookup

@@ -17,6 +17,7 @@ from __future__ import annotations
 
 import numpy as np
 
+import jax
 import jax.numpy as jnp
 
 WORD_BITS = 32
@@ -58,7 +59,7 @@ def unpack_reference(words: jnp.ndarray) -> jnp.ndarray:
 
 def pack_reference(mask: jnp.ndarray) -> jnp.ndarray:
     n = mask.shape[0]
-    lanes = mask.reshape(n // WORD_BITS, WORD_BITS).astype(jnp.uint32)
+    lanes = mask.astype(jnp.uint32).reshape(n // WORD_BITS, WORD_BITS)
     shifts = jnp.arange(WORD_BITS, dtype=jnp.uint32)
     return jnp.sum(lanes << shifts, axis=1, dtype=jnp.uint32)
 
@@ -77,20 +78,28 @@ def lookup_reference(words: jnp.ndarray, ids: jnp.ndarray) -> jnp.ndarray:
 
 def build_reference(ids: jnp.ndarray, valid: jnp.ndarray, nwords: int) -> jnp.ndarray:
     """Build a packed bitset from (possibly duplicated) ids with a validity
-    mask. XLA has no scatter-OR combiner over packed words (duplicate ids
-    landing in one word would need an OR accumulator), so this is the
-    closest single-pass shape: one byte-lane scatter (duplicate-safe — all
-    updates write the same 1), then a 32-lane shift-OR fold per word. The
-    lanes scatter at uint8 instead of bool so the fold widens straight to
-    the word dtype; the Pallas backend runs the fold in-kernel."""
+    mask; ids outside ``[0, nwords * 32)`` are dropped. XLA has no
+    scatter-OR combiner over packed words (duplicate ids landing in one
+    word would need an OR accumulator), so this is the closest single-pass
+    shape: one int32 lane scatter (duplicate-safe: all updates write the
+    same 1), then a shift-OR fold of 32 lanes per word. Lanes are laid out
+    bit-major, ``(32, stride)`` with a 1024-aligned stride, so the fold is
+    32 contiguous rows. On the TPU compiler a bool or uint8 scatter, or a
+    fold over a ``(nwords, 32)`` view, takes seconds to compile at
+    million-node widths; this shape takes under one."""
     n_bits = nwords * WORD_BITS
-    idx = jnp.where(valid, ids, np.int32(n_bits))
-    lanes = jnp.zeros((n_bits,), jnp.uint8).at[idx].set(
-        np.uint8(1), mode="drop"
+    stride = -(-nwords // 1024) * 1024
+    wb = np.int32(WORD_BITS)
+    ok = valid & (ids >= 0) & (ids < np.int32(n_bits))
+    idx = jnp.where(ok, (ids % wb) * np.int32(stride) + ids // wb,
+                    np.int32(WORD_BITS * stride))
+    lanes = jnp.zeros((WORD_BITS * stride,), jnp.int32).at[idx].set(
+        np.int32(1), mode="drop"
     )
-    lanes32 = lanes.reshape(nwords, WORD_BITS).astype(jnp.uint32)
-    shifts = jnp.arange(WORD_BITS, dtype=jnp.uint32)
-    return jnp.sum(lanes32 << shifts, axis=1, dtype=jnp.uint32)
+    shifts = jax.lax.broadcasted_iota(jnp.int32, (WORD_BITS, stride), 0)
+    # distinct bits, so the int32 sum is their OR (bit 31 wraps to the sign)
+    words = jnp.sum(lanes.reshape(WORD_BITS, stride) << shifts, axis=0)
+    return words[:nwords].astype(jnp.uint32)
 
 
 def or_reference(a: jnp.ndarray, b: jnp.ndarray) -> jnp.ndarray:

@@ -1,6 +1,6 @@
 """Pure-jnp oracle for the fused STwig expansion (MatchSTwig steps 2-3).
 
-Factored out of `repro.core.match.match_stwig_shard`'s per-child loop so the
+Factored out of `repro.core.match.expand_stwig_shard`'s per-child loop so the
 logic exists once, behind the `Kernels` registry: per-child candidate-edge
 filtering (dst-label equality ∧ binding-bit membership ∧ root candidacy)
 followed by per-root compaction into fixed-capacity candidate lists.
@@ -39,9 +39,9 @@ from __future__ import annotations
 
 import numpy as np
 
-import jax
 import jax.numpy as jnp
 
+from repro.kernels import scan as _scan
 from repro.kernels.bitset.ref import lookup_reference
 
 
@@ -74,10 +74,8 @@ def stwig_expand_reference(
         m = root_ok & (dst_labels == np.int32(child_labels[i]))
         if child_bound[i]:
             m &= lookup_reference(words_k[i], dst_ids)
-        ic = jnp.cumsum(m.astype(jnp.int32))
-        nxt = jax.lax.associative_scan(
-            jnp.minimum, jnp.where(m, edge_idx, iE), reverse=True
-        )
+        ic = _scan.cumsum(m)
+        nxt = _scan.suffix_min(jnp.where(m, edge_idx, iE), iE)
         # nxt_pad[E] = E so the chain saturates at the sentinel
         nxt_pad = jnp.concatenate([nxt, jnp.full((1,), iE, jnp.int32)])
         base = jnp.where(
@@ -89,15 +87,20 @@ def stwig_expand_reference(
             np.int32(0),
         )
         cnt = last - base  # (cap+1,) exact counts
+        # one 1-D gather of destinations per chain step: a single gather
+        # through the stacked (cap+1, C) index takes the TPU compiler about
+        # a minute at some widths (cap 2^20, E 2^23)
         e = jnp.take(nxt_pad, jnp.minimum(lo, iE), mode="clip")
-        es = [e]
-        for _ in range(C - 1):
-            e = jnp.take(nxt_pad, jnp.minimum(e + np.int32(1), iE), mode="clip")
-            es.append(e)
-        ee = jnp.stack(es, axis=1)  # (cap+1, C)
+        vals = []
+        for p in range(C):
+            if p:
+                e = jnp.take(nxt_pad, jnp.minimum(e + np.int32(1), iE), mode="clip")
+            vals.append(
+                jnp.take(dst_ids, jnp.minimum(e, iE - np.int32(1)), mode="clip")
+            )
         c_i = jnp.where(
             slots[None, :] < cnt[:, None],
-            jnp.take(dst_ids, jnp.minimum(ee, iE - np.int32(1)), mode="clip"),
+            jnp.stack(vals, axis=1),  # (cap+1, C)
             np.int32(n_total),
         )
         cands.append(c_i)

@@ -18,6 +18,7 @@ from repro.configs import get
 from repro.configs.base import LMConfig
 from repro.graphstore import generators
 from repro.models import transformer as tf
+from repro.runtime import compile_cache
 from repro.workloads import dfs_query
 
 
@@ -97,8 +98,11 @@ def main() -> None:
                     "block-join quanta across (continuous batching)")
     ap.add_argument("--tokens", type=int, default=16)
     ap.add_argument("--batch", type=int, default=4)
-    ap.add_argument("--smoke", action="store_true", default=True)
+    ap.add_argument("--smoke", action=argparse.BooleanOptionalAction,
+                    default=True, help="the config's smoke size (--no-smoke "
+                    "for its full size)")
     args = ap.parse_args()
+    compile_cache.enable()
     if args.arch == "stwig":
         serve_stwig(args)
     else:

@@ -501,8 +501,11 @@ class QueryServer:
                 if entry.stream is not None
                 else MatchStats(backend=self.session.backend)
             )
+            # a met first-K budget is a whole answer even if a block
+            # overflowed on the way (`repro.core.result.first_k_met`)
+            budget_met = bool(entry.budget) and entry.emitted >= entry.budget
             complete = (
-                all(p.complete for p in entry.pages)
+                (budget_met or all(p.complete for p in entry.pages))
                 and stats.degrade_reason is None
                 and error is None
             )

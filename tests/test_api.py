@@ -174,7 +174,9 @@ def test_adaptive_growth_through_facade(session, queries):
 
 PARITY_SCRIPT = r"""
 import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
+os.environ["XLA_FLAGS"] = (
+    "--xla_force_host_platform_device_count=8 --xla_cpu_use_fusion_emitters=false"
+)
 import json, sys, time
 import numpy as np
 sys.path.insert(0, %r)

@@ -312,7 +312,9 @@ def test_end_to_end_local_jnp_vs_pallas_interpret():
 
 SHARDED_PARITY_SCRIPT = r"""
 import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+os.environ["XLA_FLAGS"] = (
+    "--xla_force_host_platform_device_count=4 --xla_cpu_use_fusion_emitters=false"
+)
 import json, sys
 import numpy as np
 sys.path.insert(0, %r)

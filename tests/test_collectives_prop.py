@@ -28,11 +28,13 @@ SRC = str(pathlib.Path(__file__).resolve().parents[1] / "src")
 
 SCRIPT = r"""
 import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
+os.environ["XLA_FLAGS"] = (
+    "--xla_force_host_platform_device_count=8 --xla_cpu_use_fusion_emitters=false"
+)
 import json
 import numpy as np, jax, jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
-from repro.compat import shard_map
+from jax import shard_map
 from repro.core.collectives import (
     gather_load_set, gather_load_set_ring, or_allreduce,
 )

@@ -19,7 +19,9 @@ TESTS = str(pathlib.Path(__file__).resolve().parent)
 
 SCRIPT = r"""
 import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
+os.environ["XLA_FLAGS"] = (
+    "--xla_force_host_platform_device_count=8 --xla_cpu_use_fusion_emitters=false"
+)
 import json, sys
 import numpy as np, jax, jax.numpy as jnp
 from jax.sharding import Mesh
@@ -29,7 +31,7 @@ from repro.graphstore import PartitionedGraph, generators
 from repro.core import QueryGraph
 from repro.core.dist import DistributedMatcher
 from repro.core.collectives import or_allreduce
-from repro.compat import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 out = {}

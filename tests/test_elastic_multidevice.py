@@ -15,7 +15,9 @@ SRC = str(pathlib.Path(__file__).resolve().parents[1] / "src")
 
 SCRIPT = r"""
 import os, tempfile
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
+os.environ["XLA_FLAGS"] = (
+    "--xla_force_host_platform_device_count=8 --xla_cpu_use_fusion_emitters=false"
+)
 import json
 import numpy as np, jax, jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
@@ -37,7 +39,10 @@ def make_step(mesh):
     return step
 
 # phase 1: 8-device mesh, 10 steps, checkpoint
-mesh8 = jax.make_mesh((8,), ("d",))
+# Auto axes: JAX 0.9's make_mesh defaults to Explicit ones, which
+# with_sharding_constraint refuses
+AUTO = (jax.sharding.AxisType.Auto,)
+mesh8 = jax.make_mesh((8,), ("d",), axis_types=AUTO)
 params = {"w": jax.device_put(jnp.zeros((8, 64), jnp.float32), NamedSharding(mesh8, P("d")))}
 state = optim.init(cfg, params)
 step8 = make_step(mesh8)
@@ -47,7 +52,9 @@ ck = Checkpointer(tmp, async_save=False)
 ck.save(10, (params, state))
 
 # phase 2: "lost half the pod" — restore onto a 4-device mesh, train 10 more
-mesh4 = jax.make_mesh((4,), ("d",), devices=np.array(jax.devices()[:4]))
+mesh4 = jax.make_mesh(
+    (4,), ("d",), devices=np.array(jax.devices()[:4]), axis_types=AUTO
+)
 sh4 = jax.tree.map(lambda _: NamedSharding(mesh4, P()), (params, state))
 sh4[0]["w"] = NamedSharding(mesh4, P("d"))
 (restored, step_no) = elastic_restore(ck, (params, state), sh4)

@@ -82,3 +82,22 @@ def test_no_matches():
     q = QueryGraph.build([0, 0, 0, 0], [(a, b) for a in range(4) for b in range(a + 1, 4)])
     res = m.match(q, max_matches=0)
     assert res.complete and res.n_matches == 0
+
+
+def test_first_k_met_is_complete_despite_overflow(small_graph):
+    """A capacity overflow only drops matches, so a first-K query that still
+    finds its K rows is complete without escalating; one row more than the
+    overflowing plan finds is not."""
+    from repro.api import GraphSession
+
+    g, _ = small_graph
+    s = GraphSession.open(g)
+    q = dfs_query(g, np.random.default_rng(0), 4)
+    capped = s.compile(q, max_matches=0, child_cap=2).run(adaptive=False)
+    assert not capped.complete and capped.n_matches > 0
+    k = capped.n_matches
+    met = s.compile(q, max_matches=k, child_cap=2).run()
+    assert met.complete and met.n_matches == k and met.stats.retries == 0
+    assert set(map(tuple, met.rows.tolist())) <= nx_oracle(g, q)
+    short = s.compile(q, max_matches=k + 1, child_cap=2).run(adaptive=False)
+    assert not short.complete and short.n_matches == k

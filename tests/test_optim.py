@@ -51,7 +51,7 @@ def test_grad_compression_error_feedback():
     g = jnp.asarray(rng.normal(size=(256,)), jnp.float32) * 1e-3
 
     mesh = jax.make_mesh((1,), ("d",))
-    from repro.compat import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     @jax.jit

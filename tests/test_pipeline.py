@@ -16,7 +16,9 @@ SRC = str(pathlib.Path(__file__).resolve().parents[1] / "src")
 
 SCRIPT = r"""
 import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=2"
+os.environ["XLA_FLAGS"] = (
+    "--xla_force_host_platform_device_count=2 --xla_cpu_use_fusion_emitters=false"
+)
 import json
 import numpy as np, jax, jax.numpy as jnp
 from repro.launch.pipeline import gpipe

@@ -1,14 +1,15 @@
 """Roofline machinery: HLO collective parser, term arithmetic, flop models."""
 import numpy as np
 
+import pytest
+
 from repro.analysis.roofline import (
-    HBM_BW,
-    ICI_BW,
-    PEAK_FLOPS,
+    PEAKS,
     Roofline,
     engine_rooflines,
     model_flops_lm,
     parse_collectives,
+    peaks_for,
 )
 from repro.configs import get
 
@@ -43,13 +44,17 @@ def test_parse_collectives_kinds_and_bytes():
     assert np.isclose(st.bytes_by_kind["collective-permute"], 256 * 4)
 
 
+V5E = PEAKS["TPU v5 lite"]
+
+
 def test_roofline_terms_and_bottleneck():
     r = Roofline(
-        flops=PEAK_FLOPS,         # exactly 1 s of compute
-        hbm_bytes=HBM_BW / 2,     # 0.5 s
-        collective_bytes=ICI_BW * 2,  # 2 s
+        flops=V5E.flops,             # exactly 1 s of compute
+        hbm_bytes=V5E.hbm_bw / 2,    # 0.5 s
+        collective_bytes=V5E.ici_bw * 2,  # 2 s
         n_chips=4,
-        model_flops=PEAK_FLOPS * 4,  # ideal = 1 s/chip
+        peaks=V5E,
+        model_flops=V5E.flops * 4,   # ideal = 1 s/chip
     )
     assert np.isclose(r.t_compute, 1.0)
     assert np.isclose(r.t_memory, 0.5)
@@ -64,7 +69,7 @@ def test_engine_rooflines_attribute_matcher_entry_points():
     recorded engine entry point, no dry-run artifacts involved. One
     (engine x kernels) combination keeps the probe cheap; the benchmark
     suite (bench_roofline) runs all four."""
-    rooflines = engine_rooflines(backends=("local",), kernels=("jnp",))
+    rooflines = engine_rooflines(backends=("local",), kernels=("jnp",), peaks=V5E)
     # the probe query decomposes into >=2 STwigs: match AND join entry
     # points must both be recorded and attributed
     targets = set(rooflines)
@@ -79,6 +84,13 @@ def test_engine_rooflines_attribute_matcher_entry_points():
         assert d["bottleneck"] == r.bottleneck
     # single-process probe moves no collective bytes -> never the bottleneck
     assert all(r.bottleneck != "collective" for r in rooflines.values())
+
+
+def test_peaks_keyed_by_device_kind():
+    assert peaks_for("TPU v5 lite") == V5E
+    assert V5E.flops == 197e12 and V5E.hbm_bw == 819e9
+    with pytest.raises(ValueError, match="no published peaks"):
+        peaks_for("cpu")
 
 
 def test_model_flops_published_configs():

@@ -90,6 +90,27 @@ def test_expired_deadline_sheds_at_admission_without_device_work():
     }
 
 
+def test_met_first_k_budget_is_served_despite_overflow():
+    """The server streams at the compiled caps and never escalates: a query
+    whose caps overflow is still served whole once its first-K budget is
+    met, and partial when the budget is one row more than the caps allow."""
+    g = generators.rmat(120, 420, 4, seed=7, symmetrize=True)
+    s = GraphSession.open(g)
+    q = dfs_query(g, np.random.default_rng(0), 4)
+    capped = s.compile(q, max_matches=0, child_cap=2).run(adaptive=False)
+    assert not capped.complete and capped.n_matches > 0
+    k = capped.n_matches
+    server = s.serve(max_inflight=2)
+    met = server.submit(q, max_matches=k, child_cap=2)
+    short = server.submit(q, max_matches=k + 1, child_cap=2)
+    server.run_until_idle()
+    met, short = met.result(timeout=1), short.result(timeout=1)
+    assert met.status == "served" and met.n_matches == k
+    assert set(map(tuple, met.rows.tolist())) <= nx_oracle(g, q)
+    assert short.status == "partial" and short.n_matches == k
+    assert short.stats.degrade_reason is None
+
+
 def test_direct_engine_construction_warns():
     from repro.core.dist import DistributedMatcher  # noqa: F401
     from repro.core.engine import SubgraphMatcher
@@ -249,7 +270,9 @@ def test_first_k_budget_stops_join_work(session, queries):
 
 SCRIPT = r"""
 import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
+os.environ["XLA_FLAGS"] = (
+    "--xla_force_host_platform_device_count=8 --xla_cpu_use_fusion_emitters=false"
+)
 import json, sys
 import numpy as np
 sys.path.insert(0, %r)

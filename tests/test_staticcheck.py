@@ -264,7 +264,7 @@ def test_jaxpr_out_dtype_trace_failure_is_a_finding():
 
 
 def test_jaxpr_dtype_width_planted():
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         fs = _check_fake(
             lambda x: x.astype(jnp.float64), out_dtypes=("float64",)
         )
@@ -296,7 +296,7 @@ def _shard_trace(body, mesh_axes, in_specs, out_specs, *args):
     import numpy as np
 
     from jax.sharding import Mesh
-    from repro.compat import shard_map
+    from jax import shard_map
 
     devs = np.array(jax.devices()[:1]).reshape((1,) * len(mesh_axes))
     mesh = Mesh(devs, tuple(mesh_axes))
