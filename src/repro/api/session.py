@@ -21,6 +21,7 @@ from repro.core.query import QueryGraph
 from repro.core.result import MatchResult
 from repro.graphstore.csr import Graph
 from repro.graphstore.partition import PartitionedGraph
+from repro.runtime.spans import span
 
 BACKENDS = ("auto", "local", "sharded")
 
@@ -138,8 +139,9 @@ class GraphSession:
         ``join_rows_cap``, ``max_matches``, ...). Executables are built
         lazily on first run and cached in the session by their static spec,
         so recompiling an identical query is free."""
-        plan = self._engine.plan(query, **caps)
-        return CompiledQuery(session=self, query=query, plan=plan, caps=caps)
+        return CompiledQuery(
+            session=self, query=query, plan=self.replan(query, **caps), caps=caps
+        )
 
     def run(
         self,
@@ -241,7 +243,9 @@ class GraphSession:
         return self
 
     def replan(self, query: QueryGraph, **caps) -> QueryPlan:
-        return self._engine.plan(query, **caps)
+        """Plan ``query`` at ``caps``, in the span ``repro.plan``."""
+        with span("plan"):
+            return self._engine.plan(query, **caps)
 
     def close(self) -> None:
         self.cache.clear()

@@ -47,12 +47,8 @@ from repro.core.query import QueryGraph
 from repro.core.result import MatchPage, MatchResult, MatchStats, first_k_met
 from repro.core.stream import stream_blocks
 from repro.runtime.chaos import ShardFaultError
-from repro.runtime.resilience import (
-    DegradeReason,
-    RetryPolicy,
-    adaptive_run,
-    stage,
-)
+from repro.runtime.resilience import DegradeReason, RetryPolicy, adaptive_run
+from repro.runtime.spans import host_get, span, stage
 from repro.graphstore.cluster_graph import ClusterGraphIndex
 from repro.graphstore.partition import PartitionedGraph
 
@@ -577,7 +573,7 @@ class DistributedMatcher:
         # one host copy of the head validity mask: blocks where no shard has
         # a valid head row are provably empty and skipped without any device
         # call (matching the local backend's empty-block behaviour)
-        head_any = np.asarray(jax.device_get(head_valid)).any(axis=0)
+        head_any = np.asarray(host_get(stats, head_valid)).any(axis=0)
         return _ShardedStreamState(
             plan=plan,
             stats=stats,
@@ -625,9 +621,9 @@ class DistributedMatcher:
             )
         with stage(state.stats, "materialize"):
             rows = self._union_rows(
-                cols, valid, state.schemas, state.order, max_matches=0
+                cols, valid, state.schemas, state.order, 0, state.stats
             )
-        return rows, bool(jnp.any(ovf))
+        return rows, bool(np.any(host_get(state.stats, ovf)))
 
     # ------------------------------------------------------ execution phases
     def _explore(self, plan: QueryPlan, stats: MatchStats):
@@ -641,43 +637,51 @@ class DistributedMatcher:
         )
         overflow = False
         all_cols, all_valids = [], []
-        for spec in plan.specs:
+        for i, spec in enumerate(plan.specs):
             expand, emit = self._match_fns(spec)
-            exp, n_roots_max = expand(self._g.tree(), bind)
-            round_cols, round_valids = [], []
-            contrib = None
-            n_rows_tot = 0
-            r = 0
-            while True:
-                cols, valid, n_rows, cw, ovf = emit(
-                    self._g.tree(), exp, jnp.int32(r)
-                )
-                # each shard's valid rows lead its table: keep the rows
-                # the fullest shard needs, and no empty round past the first
-                n_rows = np.asarray(n_rows)
-                if n_rows.any() or not round_cols:
-                    keep = join_lib.fitted_cap(int(n_rows.max()), spec.rows_cap)
-                    round_cols.append(cols[:, :keep])
-                    round_valids.append(valid[:, :keep])
-                contrib = cw if contrib is None else jnp.bitwise_or(contrib, cw)
-                n_rows_tot += int(n_rows.sum())
-                overflow |= bool(ovf)
-                r += 1
-                if r * spec.root_cap >= int(n_roots_max):
-                    break
-            # apply binding replacement on the replicated bitset
-            new_bind = bind
-            for pos, qn in enumerate(spec.qnodes):
-                new_bind = new_bind.at[qn].set(contrib[pos])
-            bind = jax.device_put(new_bind, self._rep)
-            # concatenate rounds along the per-shard row axis
-            all_cols.append(jnp.concatenate(round_cols, axis=1))
-            all_valids.append(jnp.concatenate(round_valids, axis=1))
-            stats.stwig_rows.append(n_rows_tot)
-            # parity with the local backend's stats (max over shards: the
-            # round count is driven by the most loaded shard)
-            stats.stwig_roots.append(int(n_roots_max))
-            stats.rounds.append(r)
+            # the spans of the local engine's `_explore`
+            with span("stwig", i=i) as stwig_span:
+                with stage(stats, "expand"):
+                    exp, n_roots_max = expand(self._g.tree(), bind)
+                    n_roots = int(host_get(stats, n_roots_max))
+                stwig_span.set_metadata(n_roots=n_roots)
+                round_cols, round_valids = [], []
+                contrib = None
+                n_rows_tot = 0
+                r = 0
+                with stage(stats, "emit"):
+                    while True:
+                        with span("round", r=r):
+                            cols, valid, n_rows, cw, ovf = emit(
+                                self._g.tree(), exp, jnp.int32(r)
+                            )
+                            # each shard's valid rows lead its table: keep
+                            # the rows the fullest shard needs, and no empty
+                            # round past the first
+                            n_rows = np.asarray(host_get(stats, n_rows))
+                            overflow |= bool(host_get(stats, ovf))
+                        if n_rows.any() or not round_cols:
+                            keep = join_lib.fitted_cap(int(n_rows.max()), spec.rows_cap)
+                            round_cols.append(cols[:, :keep])
+                            round_valids.append(valid[:, :keep])
+                        contrib = cw if contrib is None else jnp.bitwise_or(contrib, cw)
+                        n_rows_tot += int(n_rows.sum())
+                        r += 1
+                        if r * spec.root_cap >= n_roots:
+                            break
+                # apply binding replacement on the replicated bitset
+                new_bind = bind
+                for pos, qn in enumerate(spec.qnodes):
+                    new_bind = new_bind.at[qn].set(contrib[pos])
+                bind = jax.device_put(new_bind, self._rep)
+                # concatenate rounds along the per-shard row axis
+                all_cols.append(jnp.concatenate(round_cols, axis=1))
+                all_valids.append(jnp.concatenate(round_valids, axis=1))
+                stats.stwig_rows.append(n_rows_tot)
+                # parity with the local backend's stats (max over shards: the
+                # round count is driven by the most loaded shard)
+                stats.stwig_roots.append(n_roots)
+                stats.rounds.append(r)
         return all_cols, all_valids, overflow
 
     def _load_masks(self, query: QueryGraph, plan: QueryPlan):
@@ -689,11 +693,13 @@ class DistributedMatcher:
         )
         return load, masks
 
-    def _union_rows(self, cols, valid, schemas, order, max_matches: int) -> np.ndarray:
+    def _union_rows(
+        self, cols, valid, schemas, order, max_matches: int, stats: MatchStats
+    ) -> np.ndarray:
         """Disjoint per-shard union → host rows of ORIGINAL ids in query-node
         column order (the sharded counterpart of `SubgraphMatcher._materialize`)."""
-        cols_h = np.asarray(jax.device_get(cols)).reshape(-1, cols.shape[-1])
-        valid_h = np.asarray(jax.device_get(valid)).reshape(-1)
+        cols_h = np.asarray(host_get(stats, cols)).reshape(-1, cols.shape[-1])
+        valid_h = np.asarray(host_get(stats, valid)).reshape(-1)
         rows_new = cols_h[valid_h]
         if max_matches and rows_new.shape[0] > max_matches:
             rows_new = rows_new[:max_matches]
@@ -739,7 +745,7 @@ class DistributedMatcher:
         )
         with stage(stats, "join"):
             all_cols, all_valids = zip(*(
-                join_lib.compact_stacked(c, v)
+                join_lib.compact_stacked(c, v, stats)
                 for c, v in zip(all_cols, all_valids)
             ))
         caps = tuple(int(c.shape[1]) for c in all_cols)
@@ -757,17 +763,15 @@ class DistributedMatcher:
             cols, valid, n_rows, ovf = jfn(
                 tuple(all_cols), tuple(all_valids), load_masks
             )
-            overflow |= bool(jnp.any(ovf))
+            overflow |= bool(np.any(host_get(stats, ovf)))
 
         # ---- union across shards (already disjoint) ------------------------
         with stage(stats, "materialize"):
             rows_old = self._union_rows(
-                cols, valid, schemas, order, plan.max_matches
+                cols, valid, schemas, order, plan.max_matches, stats
             )
         stats.time_s = time.perf_counter() - t0
         stats.join_order = [schemas[i].qnodes for i in order]
-        stats.cache_hits = self.cache.hits
-        stats.cache_misses = self.cache.misses
         if fault:
             stats.degrade_reason = DegradeReason.SHARD_FAULT.value
         return MatchResult(
@@ -806,7 +810,7 @@ class DistributedMatcher:
                 break
             except ShardFaultError as e:
                 if attempt >= policy.fetch_retries:
-                    all_valids = self._mask_shard(all_valids, e.shard)
+                    all_valids = self._mask_shard(stats, all_valids, e.shard)
                     stats.shard_health[e.shard] = "dead"
                     fault = True
                     break
@@ -817,7 +821,7 @@ class DistributedMatcher:
         if tr is not None:
             k, keep = tr
             all_valids = self._mask_shard(
-                all_valids, k, head_pos=head_pos, keep_frac=keep
+                stats, all_valids, k, head_pos=head_pos, keep_frac=keep
             )
             if stats.shard_health.get(k) == "ok":
                 stats.shard_health[k] = "truncated"
@@ -826,6 +830,7 @@ class DistributedMatcher:
 
     def _mask_shard(
         self,
+        stats: MatchStats,
         all_valids,
         shard: int,
         head_pos: int | None = None,
@@ -843,7 +848,7 @@ class DistributedMatcher:
             if keep_frac is not None and i == head_pos:
                 out.append(v)
                 continue
-            vh = np.array(jax.device_get(v))
+            vh = np.array(host_get(stats, v))
             if keep_frac is None:
                 vh[shard] = False
             else:

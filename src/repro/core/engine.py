@@ -25,20 +25,15 @@ from repro.core import join as join_lib
 from repro.core.backend import Kernels, n_words, resolve_kernels
 from repro.core.cache import ExecutableCache
 from repro.core.deprecation import warn_direct_construction
-from repro.core.match import (
-    Bindings,
-    ShardGraph,
-    STwigTable,
-    apply_binding_update,
-    emit_stwig_round,
-    expand_stwig_shard,
-)
+from repro.core import match as match_lib
+from repro.core.match import Bindings, ShardGraph, STwigTable, apply_binding_update
 from repro.core.plan import QueryPlan, STwigSpec, caps_from_plan, make_plan
 from repro.core.query import QueryGraph
 from repro.core.result import MatchPage, MatchResult, MatchStats, first_k_met
 from repro.core.stream import stream_blocks
 from repro.graphstore.partition import PartitionedGraph
-from repro.runtime.resilience import RetryPolicy, adaptive_run, grow_caps, stage
+from repro.runtime.resilience import RetryPolicy, adaptive_run, grow_caps
+from repro.runtime.spans import host_get, span, stage
 
 __all__ = [
     "MatchResult",
@@ -73,13 +68,19 @@ def stwig_step_fns(spec: STwigSpec, kernels: Kernels, n_words_: int):
     """The two halves of one STwig step as the local engine jits them:
     ``expand(g, bind)`` once per STwig, then ``emit(g, exp, round_idx=r)``
     per round of roots (a round then costs its roots, not another pass
-    over every edge)."""
-    return (
-        functools.partial(expand_stwig_shard, spec=spec, kernels=kernels),
-        functools.partial(
-            emit_stwig_round, spec=spec, n_words_=n_words_, kernels=kernels
-        ),
-    )
+    over every edge). Each is a named function, so that its device program
+    is named after it (``jit_expand_stwig_shard``, ``jit_emit_stwig_round``)."""
+
+    def expand_stwig_shard(g, bind):
+        return match_lib.expand_stwig_shard(g, bind, spec=spec, kernels=kernels)
+
+    def emit_stwig_round(g, exp, round_idx):
+        return match_lib.emit_stwig_round(
+            g, exp, spec=spec, round_idx=round_idx, n_words_=n_words_,
+            kernels=kernels,
+        )
+
+    return expand_stwig_shard, emit_stwig_round
 
 
 @dataclasses.dataclass(eq=False)
@@ -166,8 +167,9 @@ class SubgraphMatcher:
 
         def build():
             merged, _ = schema_a.merge(schema_b)
-            fn = jax.jit(
-                lambda a, b: join_lib.sort_merge_join(
+
+            def sort_merge_join(a, b):
+                return join_lib.sort_merge_join(
                     a,
                     b,
                     schema_a,
@@ -176,8 +178,8 @@ class SubgraphMatcher:
                     dup_cap=dup_cap,
                     kernels=kern,
                 )[0]
-            )
-            return fn, merged
+
+            return jax.jit(sort_merge_join), merged
 
         return self.cache.get(
             ("join", schema_a, schema_b, out_cap, dup_cap, a_cap, b_cap,
@@ -259,7 +261,7 @@ class SubgraphMatcher:
         # tables, and probe cost scales with their capacity, not their row
         # count — shrink them once here (setup is already host-synced)
         for idx in order[1:]:
-            tables[idx] = join_lib.compact_table(tables[idx])
+            tables[idx] = join_lib.compact_table(tables[idx], stats)
         first = tables[order[0]]
         return _LocalStreamState(
             plan=plan,
@@ -271,7 +273,7 @@ class SubgraphMatcher:
             cap=int(first.cols.shape[0]),
             # one host copy of the blocked table's validity: empty blocks are
             # then skipped without any per-block device round-trip
-            valid_host=np.asarray(jax.device_get(first.valid)),
+            valid_host=np.asarray(host_get(stats, first.valid)),
         )
 
     def _stream_block(
@@ -302,63 +304,77 @@ class SubgraphMatcher:
                 )
                 acc, acc_schema = fn(acc, state.tables[idx]), merged
         with stage(state.stats, "materialize"):
-            rows = self._materialize(acc, acc_schema, max_matches=0)
-        return rows, bool(jax.device_get(acc.overflow))
+            rows = self._materialize(acc, acc_schema, 0, state.stats)
+        return rows, bool(host_get(state.stats, acc.overflow))
 
     # ------------------------------------------------------ execution phases
     def _explore(
         self, plan: QueryPlan, stats: MatchStats
     ) -> tuple[list[join_lib.JoinTable], list[join_lib.Schema], bool]:
-        """STwig exploration in Algorithm-2 order → per-STwig join inputs."""
+        """STwig exploration in Algorithm-2 order → per-STwig join inputs.
+
+        Each STwig runs in ``repro.stwig``: the stage ``expand`` ends with
+        the host read of its root count, so it holds the expansion the
+        query waited for; the stage ``emit`` holds every round
+        (``repro.round``), each ending with the host read of its row
+        count."""
         n_bits = self.pg.n_total + 1
         bind = Bindings.fresh(plan.n_qnodes, n_bits)
         tables: list[join_lib.JoinTable] = []
         schemas: list[join_lib.Schema] = []
         overflow = False
-        for spec in plan.specs:
+        for i, spec in enumerate(plan.specs):
             expand, emit = self._match_fns(spec)
-            exp = expand(self.g, bind)
-            n_roots = int(exp.n_roots)
-            round_tables: list[STwigTable] = []
-            contrib = None
-            r = 0
-            while True:
-                table, c = emit(self.g, exp, round_idx=jnp.int32(r))
-                # a round's valid rows lead its table: keep only those
-                # (rounds of a hub-heavy STwig are mostly empty, and
-                # rounds x rows_cap would not fit the device)
-                n = int(table.n_rows)
-                if n or not round_tables:
-                    keep = join_lib.fitted_cap(n, spec.rows_cap)
-                    round_tables.append(table._replace(
-                        cols=table.cols[:keep], valid=table.valid[:keep]
-                    ))
-                cw = c.words
-                contrib = cw if contrib is None else jnp.bitwise_or(contrib, cw)
-                r += 1
-                if r * spec.root_cap >= n_roots:
-                    break
-            bind = apply_binding_update(bind, spec, contrib)
-            jt = _concat_tables(round_tables)
-            tables.append(jt)
-            schemas.append(
-                join_lib.Schema(
-                    qnodes=spec.qnodes,
-                    qlabels=(spec.root_label,) + spec.child_labels,
+            with span("stwig", i=i) as stwig_span:
+                with stage(stats, "expand"):
+                    exp = expand(self.g, bind)
+                    n_roots = int(host_get(stats, exp.n_roots))
+                stwig_span.set_metadata(n_roots=n_roots)
+                round_tables: list[STwigTable] = []
+                contrib = None
+                r = 0
+                with stage(stats, "emit"):
+                    while True:
+                        with span("round", r=r):
+                            table, c = emit(self.g, exp, round_idx=jnp.int32(r))
+                            # a round's valid rows lead its table: keep only
+                            # those (rounds of a hub-heavy STwig are mostly
+                            # empty, and rounds x rows_cap would not fit the
+                            # device)
+                            n = int(host_get(stats, table.n_rows))
+                        if n or not round_tables:
+                            keep = join_lib.fitted_cap(n, spec.rows_cap)
+                            round_tables.append(table._replace(
+                                cols=table.cols[:keep], valid=table.valid[:keep]
+                            ))
+                        cw = c.words
+                        contrib = cw if contrib is None else jnp.bitwise_or(contrib, cw)
+                        r += 1
+                        if r * spec.root_cap >= n_roots:
+                            break
+                bind = apply_binding_update(bind, spec, contrib)
+                jt = _concat_tables(round_tables)
+                tables.append(jt)
+                schemas.append(
+                    join_lib.Schema(
+                        qnodes=spec.qnodes,
+                        qlabels=(spec.root_label,) + spec.child_labels,
+                    )
                 )
-            )
-            stats.stwig_rows.append(int(jt.n_rows))
-            stats.stwig_roots.append(int(round_tables[0].n_roots))
-            stats.rounds.append(r)
-            overflow |= bool(jax.device_get(jt.overflow))
+                stats.stwig_rows.append(int(host_get(stats, jt.n_rows)))
+                stats.stwig_roots.append(
+                    int(host_get(stats, round_tables[0].n_roots)))
+                stats.rounds.append(r)
+                overflow |= bool(host_get(stats, jt.overflow))
         return tables, schemas, overflow
 
     def _materialize(
-        self, acc: join_lib.JoinTable, acc_schema: join_lib.Schema, max_matches: int
+        self, acc: join_lib.JoinTable, acc_schema: join_lib.Schema,
+        max_matches: int, stats: MatchStats,
     ) -> np.ndarray:
         """Device join table → host rows of ORIGINAL ids in query-node order."""
-        cols = np.asarray(jax.device_get(acc.cols))
-        valid = np.asarray(jax.device_get(acc.valid))
+        cols = np.asarray(host_get(stats, acc.cols))
+        valid = np.asarray(host_get(stats, acc.valid))
         rows_new = cols[valid]
         if max_matches and rows_new.shape[0] > max_matches:
             rows_new = rows_new[:max_matches]
@@ -389,7 +405,7 @@ class SubgraphMatcher:
 
         # ---- join phase ----------------------------------------------------
         with stage(stats, "join"):
-            tables = [join_lib.compact_table(t) for t in tables]
+            tables = [join_lib.compact_table(t, stats) for t in tables]
             order = join_lib.select_join_order(schemas, stats.stwig_rows)
             acc, acc_schema = tables[order[0]], schemas[order[0]]
             for idx in order[1:]:
@@ -399,16 +415,13 @@ class SubgraphMatcher:
                     int(acc.cols.shape[0]), int(tables[idx].cols.shape[0]),
                 )
                 acc, acc_schema = fn(acc, tables[idx]), merged
-            overflow |= bool(jax.device_get(acc.overflow))
+            overflow |= bool(host_get(stats, acc.overflow))
 
         # ---- materialize (original ids, query-node column order) ----------
         with stage(stats, "materialize"):
-            rows_old = self._materialize(acc, acc_schema, plan.max_matches)
+            rows_old = self._materialize(acc, acc_schema, plan.max_matches, stats)
         stats.join_order = [tuple(schemas[i].qnodes) for i in order]
         stats.time_s = time.perf_counter() - t0
-        stats.n_join_rows = int(acc.n_rows)
-        stats.cache_hits = self.cache.hits
-        stats.cache_misses = self.cache.misses
         return MatchResult(
             rows=rows_old,
             n_matches=int(rows_old.shape[0]),

@@ -29,6 +29,7 @@ import numpy as np
 
 from repro.core.backend import Kernels, get_kernels
 from repro.kernels.scan import compact_rows, cumsum, radix_argsort
+from repro.runtime.spans import host_get, span
 
 
 class JoinTable(NamedTuple):
@@ -213,10 +214,11 @@ def _compact_host(cols: np.ndarray, valid: np.ndarray, min_cap: int):
     return out_cols, out_valid
 
 
-def compact_table(table: JoinTable, min_cap: int = 64) -> JoinTable:
+def compact_table(table: JoinTable, stats, min_cap: int = 64) -> JoinTable:
     """Squeeze a table's valid rows to the front and shrink its capacity to
-    the smallest power of two that holds them all (host-side; costs one
-    device round-trip, so callers must already be off the async path).
+    the smallest power of two that holds them all (host-side, in the span
+    ``repro.compact``; its two reads are host syncs of ``stats``' query, so
+    callers must already be off the async path).
 
     STwig tables are allocated at worst-case capacity (``rows_cap`` per
     exploration round) but are usually sparse, and a join pays
@@ -227,34 +229,42 @@ def compact_table(table: JoinTable, min_cap: int = 64) -> JoinTable:
     compact capacity covers every valid row, rows keep their order, and
     the exact-count/overflow flags are carried over unchanged.
     """
-    out = _compact_host(
-        np.asarray(table.cols)[None], np.asarray(table.valid)[None], min_cap
-    )
-    if out is None:
-        return table
-    return JoinTable(
-        cols=jnp.asarray(out[0][0]),
-        valid=jnp.asarray(out[1][0]),
-        n_rows=table.n_rows,
-        overflow=table.overflow,
-    )
+    with span("compact"):
+        out = _compact_host(
+            np.asarray(host_get(stats, table.cols))[None],
+            np.asarray(host_get(stats, table.valid))[None],
+            min_cap,
+        )
+        if out is None:
+            return table
+        return JoinTable(
+            cols=jnp.asarray(out[0][0]),
+            valid=jnp.asarray(out[1][0]),
+            n_rows=table.n_rows,
+            overflow=table.overflow,
+        )
 
 
-def compact_stacked(cols, valid, min_cap: int = 64):
+def compact_stacked(cols, valid, stats, min_cap: int = 64):
     """`compact_table` for per-shard stacked tables ``cols (S, cap, w)`` /
     ``valid (S, cap)``: every shard's valid rows move to the front, and the
     common capacity shrinks to the power of two that holds the fullest
     shard. The results keep the inputs' shardings."""
     import jax
 
-    out = _compact_host(np.asarray(cols), np.asarray(valid), min_cap)
-    if out is None:
-        return cols, valid
-    # cols and valid share the leading shard axis, so one sharding serves
-    return (
-        jax.device_put(out[0], cols.sharding),
-        jax.device_put(out[1], cols.sharding),
-    )
+    with span("compact"):
+        out = _compact_host(
+            np.asarray(host_get(stats, cols)),
+            np.asarray(host_get(stats, valid)),
+            min_cap,
+        )
+        if out is None:
+            return cols, valid
+        # cols and valid share the leading shard axis, so one sharding serves
+        return (
+            jax.device_put(out[0], cols.sharding),
+            jax.device_put(out[1], cols.sharding),
+        )
 
 
 def select_join_order(

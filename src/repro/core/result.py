@@ -19,8 +19,9 @@ class MatchStats:
     """Execution statistics for one query run.
 
     Per-STwig lists are indexed in exploration (Algorithm 2) order.
-    ``cache_hits``/``cache_misses`` are the owning executable cache's
-    cumulative counters at the end of the run (0 when no cache is attached).
+    `repro.runtime.spans` fills ``stage_times`` and ``host_syncs``; a run
+    through `repro.runtime.resilience.adaptive_run` sums ``host_syncs``
+    over all its attempts and times each in ``attempt_s``.
     """
 
     backend: str = "local"             # "local" | "sharded"
@@ -32,8 +33,9 @@ class MatchStats:
     # for plain first-K truncation (adaptive=False is semantics, not
     # degradation)
     degrade_reason: str | None = None
-    # wall seconds per execution stage ("explore", "fetch", "join",
-    # "materialize"), accumulated across blocks on the streaming path
+    # wall seconds per execution stage ("explore", and within it "expand"
+    # and "emit"; "fetch", "join", "materialize"), accumulated across
+    # blocks on the streaming path
     stage_times: dict[str, float] = dataclasses.field(default_factory=dict)
     # per-shard health after chaos/fault handling: shard -> "ok" | "slow" |
     # "dead" | "recovered" | "truncated" (sharded backend only)
@@ -53,10 +55,13 @@ class MatchStats:
     # the max over shards — the shard that drives the round count)
     stwig_roots: list[int] = dataclasses.field(default_factory=list)
     join_order: list[tuple[int, ...]] = dataclasses.field(default_factory=list)
-    n_join_rows: int = 0
     n_shards: int = 1
-    cache_hits: int = 0
-    cache_misses: int = 0
+    # device-to-host reads on the query path (`repro.runtime.spans.host_get`)
+    host_syncs: int = 0
+    # wall seconds of each adaptive attempt, the back-off sleep after it
+    # included; the last is the returned attempt, so sum(attempt_s[:-1]) is
+    # what escalation threw away. Empty when no attempt ran.
+    attempt_s: list[float] = dataclasses.field(default_factory=list)
 
     # -------- deprecation bridge: the old dict-style access keeps working,
     # but warns — `tests/test_api.py` pins the warning

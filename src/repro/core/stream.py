@@ -22,6 +22,9 @@ The block boundary is also the stream's preemption point: a
 before every block, and a tripped deadline ends the stream with one final
 degraded page (``complete=False``, the reason in the shared stats) — the
 pages already delivered stay valid, the remaining blocks are never joined.
+
+A stream draws its query id (`repro.runtime.spans`) when it is opened: the
+spans of its setup and of every block join carry that id.
 """
 from __future__ import annotations
 
@@ -33,6 +36,7 @@ import numpy as np
 from repro.core.plan import QueryPlan, caps_from_plan
 from repro.core.query import QueryGraph
 from repro.core.result import MatchPage
+from repro.runtime import spans
 
 
 @dataclasses.dataclass(eq=False)
@@ -50,6 +54,7 @@ class OpenStream:
     state: object
     guard: object
     block_rows: int  # effective B (clamped to the blocked table's capacity)
+    qid: int  # the query id its spans carry
 
     @property
     def stats(self):
@@ -92,9 +97,10 @@ class OpenStream:
                         stats=stats,
                     )
                     return
-            rows, block_overflow = self.engine._stream_block(
-                state, lo, self.block_rows
-            )
+            with spans.scope(self.qid):
+                rows, block_overflow = self.engine._stream_block(
+                    state, lo, self.block_rows
+                )
             faulted = stats.degrade_reason is not None
             if rows.shape[0] == 0 and not block_overflow:
                 continue
@@ -141,7 +147,9 @@ def open_stream(
     """
     if guard is not None:
         guard.start()
-    state = engine._stream_setup(query, plan, **engine_kw)
+    qid = spans.new_qid()
+    with spans.scope(qid):
+        state = engine._stream_setup(query, plan, **engine_kw)
     stats = state.stats
     stats.retries = 0
     caps = caps_from_plan(state.plan)
@@ -154,6 +162,7 @@ def open_stream(
         state=state,
         guard=guard,
         block_rows=max(1, min(block_rows, state.cap)),
+        qid=qid,
     )
 
 

@@ -17,6 +17,11 @@ gives each root's edge segment ``[lo, hi)`` directly:
     ``nxt``: ``e0 = nxt[lo]``, ``e_{p+1} = nxt[e_p + 1]`` — each root's
     first ``child_cap`` survivors in edge order, no sort, no scatter.
 
+Each phase of each child runs under a `jax.named_scope`
+(``stwig_expand/child<i>/survivors`` for the passes over every edge slot,
+``.../gather`` for the chain, ``.../cap`` for the cut at the count), so a
+profiler trace's device operations say which phase they belong to.
+
 All ``k`` children share the one pass structure (the mask/cumsum/cummin
 stage is per child but nothing is re-ranked per root), which is what makes
 this the fast CPU hot path: the old formulation scattered every edge into a
@@ -39,6 +44,7 @@ from __future__ import annotations
 
 import numpy as np
 
+import jax
 import jax.numpy as jnp
 
 from repro.kernels import scan as _scan
@@ -71,38 +77,44 @@ def stwig_expand_reference(
     edge_idx = jnp.arange(E, dtype=jnp.int32)
     cands, cnts = [], []
     for i in range(k):
-        m = root_ok & (dst_labels == np.int32(child_labels[i]))
-        if child_bound[i]:
-            m &= lookup_reference(words_k[i], dst_ids)
-        ic = _scan.cumsum(m)
-        nxt = _scan.suffix_min(jnp.where(m, edge_idx, iE), iE)
-        # nxt_pad[E] = E so the chain saturates at the sentinel
-        nxt_pad = jnp.concatenate([nxt, jnp.full((1,), iE, jnp.int32)])
-        base = jnp.where(
-            lo > 0, jnp.take(ic, jnp.maximum(lo - 1, 0), mode="clip"),
-            np.int32(0),
-        )
-        last = jnp.where(
-            hi > 0, jnp.take(ic, jnp.maximum(hi - 1, 0), mode="clip"),
-            np.int32(0),
-        )
-        cnt = last - base  # (cap+1,) exact counts
-        # one 1-D gather of destinations per chain step: a single gather
-        # through the stacked (cap+1, C) index takes the TPU compiler about
-        # a minute at some widths (cap 2^20, E 2^23)
-        e = jnp.take(nxt_pad, jnp.minimum(lo, iE), mode="clip")
-        vals = []
-        for p in range(C):
-            if p:
-                e = jnp.take(nxt_pad, jnp.minimum(e + np.int32(1), iE), mode="clip")
-            vals.append(
-                jnp.take(dst_ids, jnp.minimum(e, iE - np.int32(1)), mode="clip")
+        scope = f"stwig_expand/child{i}/"
+        with jax.named_scope(scope + "survivors"):
+            m = root_ok & (dst_labels == np.int32(child_labels[i]))
+            if child_bound[i]:
+                m &= lookup_reference(words_k[i], dst_ids)
+            ic = _scan.cumsum(m)
+            nxt = _scan.suffix_min(jnp.where(m, edge_idx, iE), iE)
+            # nxt_pad[E] = E so the chain saturates at the sentinel
+            nxt_pad = jnp.concatenate([nxt, jnp.full((1,), iE, jnp.int32)])
+            base = jnp.where(
+                lo > 0, jnp.take(ic, jnp.maximum(lo - 1, 0), mode="clip"),
+                np.int32(0),
             )
-        c_i = jnp.where(
-            slots[None, :] < cnt[:, None],
-            jnp.stack(vals, axis=1),  # (cap+1, C)
-            np.int32(n_total),
-        )
+            last = jnp.where(
+                hi > 0, jnp.take(ic, jnp.maximum(hi - 1, 0), mode="clip"),
+                np.int32(0),
+            )
+            cnt = last - base  # (cap+1,) exact counts
+        with jax.named_scope(scope + "gather"):
+            # one 1-D gather of destinations per chain step: a single
+            # gather through the stacked (cap+1, C) index takes the TPU
+            # compiler about a minute at some widths (cap 2^20, E 2^23)
+            e = jnp.take(nxt_pad, jnp.minimum(lo, iE), mode="clip")
+            vals = []
+            for p in range(C):
+                if p:
+                    e = jnp.take(
+                        nxt_pad, jnp.minimum(e + np.int32(1), iE), mode="clip"
+                    )
+                vals.append(
+                    jnp.take(dst_ids, jnp.minimum(e, iE - np.int32(1)), mode="clip")
+                )
+        with jax.named_scope(scope + "cap"):
+            c_i = jnp.where(
+                slots[None, :] < cnt[:, None],
+                jnp.stack(vals, axis=1),  # (cap+1, C)
+                np.int32(n_total),
+            )
         cands.append(c_i)
         cnts.append(cnt[:cap])
     return jnp.stack(cands), jnp.stack(cnts)

@@ -28,14 +28,15 @@ This is the admission/eviction half of the future `QueryServer`
 """
 from __future__ import annotations
 
-import contextlib
 import dataclasses
 import enum
+import functools
 import random
 import time
 from typing import Callable
 
 from repro.core.result import MatchResult, MatchStats
+from repro.runtime import spans
 
 __all__ = [
     "DegradeReason",
@@ -301,6 +302,31 @@ def degraded_empty(n_qnodes: int, backend: str, reason) -> MatchResult:
     return mark_degraded(res, reason)
 
 
+def _escalation(
+    res: MatchResult,
+    retries: int,
+    caps: dict,
+    policy: RetryPolicy,
+    guard: QueryGuard | None,
+    adaptive: bool,
+) -> dict | None:
+    """The caps of the next attempt after ``res``, or None to return
+    ``res``; a stop for a typed reason stamps it onto ``res``."""
+    if not adaptive or res.complete or res.stats.degrade_reason is not None:
+        return None
+    if retries >= policy.max_retries:
+        mark_degraded(res, DegradeReason.OVERFLOW_CEILING)
+        return None
+    reason = guard.check() if guard is not None else None
+    grown = None
+    if reason is None:
+        grown, reason = policy.next_caps(caps, guard)
+    if reason is not None:
+        mark_degraded(res, reason)
+        return None
+    return grown
+
+
 def adaptive_run(
     first: Callable[[], MatchResult],
     escalate: Callable[[dict], MatchResult],
@@ -322,50 +348,44 @@ def adaptive_run(
     problem — growing caps would not help). With ``adaptive=False`` the
     first (possibly partial) result is returned — the paper's first-K
     semantics, not a degradation, so no reason is stamped.
+
+    The query runs inside ``repro.query`` under a fresh query id, each
+    attempt inside ``repro.attempt``; the returned stats carry every
+    attempt's seconds (``attempt_s``) and the host syncs of all of them.
     """
     policy = policy or RetryPolicy()
     caps = dict(caps)
-    if guard is not None:
-        guard.start()
-        reason = guard.check(
-            plan_caps_bytes(caps)
-            if guard.memory_budget_bytes is not None
-            else None
-        )
-        if reason is not None:
-            res = degraded_empty(n_qnodes, backend, reason)
-            res.stats.final_caps = _final_caps(caps)
-            return res
-    res = first()
-    retries = 0
-    while adaptive and not res.complete and res.stats.degrade_reason is None:
-        if retries >= policy.max_retries:
-            mark_degraded(res, DegradeReason.OVERFLOW_CEILING)
-            break
-        reason = guard.check() if guard is not None else None
-        grown = None
-        if reason is None:
-            grown, reason = policy.next_caps(caps, guard)
-        if reason is not None:
-            mark_degraded(res, reason)
-            break
-        policy.sleep(retries)
-        caps = grown
-        retries += 1
-        res = escalate(caps)
+    with spans.query():
+        if guard is not None:
+            guard.start()
+            reason = guard.check(
+                plan_caps_bytes(caps)
+                if guard.memory_budget_bytes is not None
+                else None
+            )
+            if reason is not None:
+                res = degraded_empty(n_qnodes, backend, reason)
+                res.stats.final_caps = _final_caps(caps)
+                return res
+        attempt_s: list[float] = []
+        host_syncs = 0
+        retries = 0
+        run = first
+        while True:
+            t0 = time.perf_counter()
+            with spans.span("attempt", attempt=retries):
+                res = run()
+                host_syncs += res.stats.host_syncs
+                grown = _escalation(res, retries, caps, policy, guard, adaptive)
+                if grown is not None:
+                    policy.sleep(retries)
+            attempt_s.append(time.perf_counter() - t0)
+            if grown is None:
+                break
+            caps, retries = grown, retries + 1
+            run = functools.partial(escalate, caps)
     res.stats.retries = retries
     res.stats.final_caps = _final_caps(caps)
+    res.stats.attempt_s = attempt_s
+    res.stats.host_syncs = host_syncs
     return res
-
-
-@contextlib.contextmanager
-def stage(stats: MatchStats, name: str):
-    """Accumulate wall time of a named execution stage into
-    ``stats.stage_times`` (re-entrant across blocks: times add up)."""
-    t0 = time.perf_counter()
-    try:
-        yield
-    finally:
-        stats.stage_times[name] = (
-            stats.stage_times.get(name, 0.0) + time.perf_counter() - t0
-        )
