@@ -64,10 +64,10 @@ def main() -> None:
     # ---- candidate filter / stwig_expand ----------------------------------
     E, cap, n_total, C = 1 << 15, 4096, n_bits - 1, 4
     src = np.sort(rng.integers(0, cap, E)).astype(np.int32)
-    # (cap+2,) CSR bounds over the edge arrays; indptr[cap+1] == E
-    indptr = jnp.asarray(
-        np.searchsorted(src, np.arange(cap + 2)).astype(np.int32)
-    )
+    # the CSR segments of one root candidate in 8 node slots
+    indptr = np.searchsorted(src, np.arange(cap + 1)).astype(np.int32)
+    roots = np.arange(0, cap, 8)
+    lo, hi = jnp.asarray(indptr[roots]), jnp.asarray(indptr[roots + 1])
     dst = jnp.asarray(rng.integers(0, n_total, E), jnp.int32)
     labs = jnp.asarray(rng.integers(0, 8, E), jnp.int32)
     rok = jnp.asarray(rng.random(E) < 0.8)
@@ -85,12 +85,11 @@ def main() -> None:
             words_k,
             dst,
             labs,
-            indptr,
-            rok,
+            lo,
+            hi,
             child_labels=(3, 5),
             child_bound=(True, False),
             child_cap=C,
-            cap=cap,
             n_total=n_total,
         ),
     )

@@ -105,13 +105,12 @@ def _contract_stwig_expand():
         _ex((2, 2), jnp.uint32),   # words_k
         _ex((16,), jnp.int32),     # dst_ids
         _ex((16,), jnp.int32),     # dst_labels
-        _ex((10,), jnp.int32),     # indptr (cap+2,)
-        _ex((16,), jnp.bool_),     # root_ok
+        _ex((8,), jnp.int32),      # lo (rows,)
+        _ex((8,), jnp.int32),      # hi (rows,)
     ), dict(
         child_labels=(1, 2),
         child_bound=(True, False),
         child_cap=4,
-        cap=8,
         n_total=63,
     )
 
@@ -191,29 +190,28 @@ class Kernels:
         words_k,
         dst_ids,
         dst_labels,
-        indptr,
-        root_ok,
+        lo,
+        hi,
         *,
         child_labels: tuple[int, ...],
         child_bound: tuple[bool, ...],
         child_cap: int,
-        cap: int,
         n_total: int,
     ) -> tuple[jnp.ndarray, jnp.ndarray]:
-        """Fused steps 2-3: per-child filter + per-root compaction into
-        candidate lists ``(k, cap+1, C)`` with exact counts ``(k, cap)``.
-        ``indptr`` is the ``(cap+2,)`` CSR bounds array (edges grouped by
-        root, ghost row ``cap`` owning the pad tail up to ``E``)."""
+        """Fused steps 2-3: per-child filter + compaction into candidate
+        lists ``(k, rows+1, C)`` with exact counts ``(k, rows)``, one row
+        per root candidate. ``lo``, ``hi`` are the ``(rows,)`` bounds of
+        each candidate's CSR edge segment, ``[E, E)`` for a pad row; row
+        ``rows`` of ``cand`` is all ghost ids."""
         return _expand_ref.stwig_expand_reference(
             words_k,
             dst_ids,
             dst_labels,
-            indptr,
-            root_ok,
+            lo,
+            hi,
             child_labels=child_labels,
             child_bound=child_bound,
             child_cap=child_cap,
-            cap=cap,
             n_total=n_total,
         )
 

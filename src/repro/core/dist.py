@@ -64,9 +64,9 @@ class _StackedGraph:
         self.labels = jax.device_put(pg.labels, sh)
         self.indptr = jax.device_put(pg.indptr, sh)
         self.indices = jax.device_put(pg.indices, sh)
-        self.edge_src = jax.device_put(pg.edge_src, sh)
         self.n_local = jax.device_put(pg.n_local, sh)
-        self.n_local_edges = jax.device_put(pg.n_local_edges, sh)
+        self.nodes_by_label = jax.device_put(pg.nodes_by_label, sh)
+        self.label_indptr = jax.device_put(pg.label_indptr, sh)
         self.all_labels = jax.device_put(pg.all_labels, rep)
 
     def tree(self):
@@ -74,22 +74,22 @@ class _StackedGraph:
             self.labels,
             self.indptr,
             self.indices,
-            self.edge_src,
             self.n_local,
-            self.n_local_edges,
+            self.nodes_by_label,
+            self.label_indptr,
             self.all_labels,
         )
 
 
 def _local_shard_graph(tree) -> ShardGraph:
-    labels, indptr, indices, edge_src, n_local, n_local_edges, all_labels = tree
+    labels, indptr, indices, n_local, nodes_by_label, label_indptr, all_labels = tree
     return ShardGraph(
         labels=labels[0],
         indptr=indptr[0],
         indices=indices[0],
-        edge_src=edge_src[0],
         n_local=n_local[0],
-        n_local_edges=n_local_edges[0],
+        nodes_by_label=nodes_by_label[0],
+        label_indptr=label_indptr[0],
         shard_id=lax.axis_index(AXIS).astype(jnp.int32),
         all_labels=all_labels,
     )
@@ -640,7 +640,11 @@ class DistributedMatcher:
         for i, spec in enumerate(plan.specs):
             expand, emit = self._match_fns(spec)
             # the spans of the local engine's `_explore`
-            with span("stwig", i=i) as stwig_span:
+            rows = spec.expand_rows(self.pg.cap)
+            stats.expand_rows += rows
+            with span(
+                "stwig", i=i, root_rows=rows, node_slots=self.pg.cap
+            ) as stwig_span:
                 with stage(stats, "expand"):
                     exp, n_roots_max = expand(self._g.tree(), bind)
                     n_roots = int(host_get(stats, n_roots_max))

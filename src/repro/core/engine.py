@@ -133,9 +133,9 @@ class SubgraphMatcher:
             labels=jnp.asarray(pg.labels[shard]),
             indptr=jnp.asarray(pg.indptr[shard]),
             indices=jnp.asarray(pg.indices[shard]),
-            edge_src=jnp.asarray(pg.edge_src[shard]),
             n_local=jnp.int32(pg.n_local[shard]),
-            n_local_edges=jnp.int32(pg.n_local_edges[shard]),
+            nodes_by_label=jnp.asarray(pg.nodes_by_label[shard]),
+            label_indptr=jnp.asarray(pg.label_indptr[shard]),
             shard_id=jnp.int32(shard),
             all_labels=jnp.asarray(pg.all_labels),
         )
@@ -325,7 +325,11 @@ class SubgraphMatcher:
         overflow = False
         for i, spec in enumerate(plan.specs):
             expand, emit = self._match_fns(spec)
-            with span("stwig", i=i) as stwig_span:
+            rows = spec.expand_rows(self.g.cap)
+            stats.expand_rows += rows
+            with span(
+                "stwig", i=i, root_rows=rows, node_slots=self.g.cap
+            ) as stwig_span:
                 with stage(stats, "expand"):
                     exp = expand(self.g, bind)
                     n_roots = int(host_get(stats, exp.n_roots))

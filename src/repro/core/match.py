@@ -4,11 +4,12 @@ The paper's per-root loop
 
     for n in Index.getID(r):  c = Cloud.Load(n); filter children by label/binding
 
-becomes one edge-parallel pass over the shard's CSR arrays:
+becomes one pass over the shard's CSR arrays:
 
-  1. root candidates  = (label == r) ∧ binding-bit(root)           (node-parallel)
+  1. root candidates  = Index.getID(r) from the shard's label index, the
+                        plan's ``root_rows`` of them, ∧ binding-bit(root)
   2. child candidates = (label[dst] == l_i) ∧ binding-bit(dst)     (edge-parallel)
-  3. per-root candidate lists via segment-rank compaction (scatter)
+  3. per-root candidate lists from each candidate's CSR segment    (per candidate)
   4. STwig emission   = masked cross-product over per-root lists
   5. binding update   = scatter-OR into packed bitsets
 
@@ -37,9 +38,11 @@ class ShardGraph(NamedTuple):
     labels: jnp.ndarray        # (cap,) int32, pad = n_labels
     indptr: jnp.ndarray        # (cap+1,) int32
     indices: jnp.ndarray       # (edge_cap,) int32 global ids, pad = n_total
-    edge_src: jnp.ndarray      # (edge_cap,) int32 local rows, pad = cap
     n_local: jnp.ndarray       # () int32
-    n_local_edges: jnp.ndarray  # () int32
+    # label index: label l's local ids, ascending, at
+    # nodes_by_label[label_indptr[l]:label_indptr[l+1]]
+    nodes_by_label: jnp.ndarray  # (cap,) int32, pad = cap
+    label_indptr: jnp.ndarray  # (n_labels+1,) int32
     shard_id: jnp.ndarray      # () int32
     all_labels: jnp.ndarray    # (n_total+1,) int32 (replicated)
 
@@ -85,7 +88,20 @@ class STwigExpansion(NamedTuple):
     root_mask: jnp.ndarray  # (cap,) bool roots with every required child
     rank: jnp.ndarray       # (cap,) int32 exclusive rank among those roots
     n_roots: jnp.ndarray    # () int32
-    overflow: jnp.ndarray   # () bool — some count exceeds child_cap
+    overflow: jnp.ndarray   # () bool — some count exceeds child_cap, or
+    #                         more root candidates than the plan's rows
+
+
+def root_candidates(g: ShardGraph, spec: STwigSpec) -> tuple[jnp.ndarray, jnp.ndarray]:
+    """The shard's node slots with the root's label, ascending, read from
+    its label index: the first ``spec.expand_rows(cap)`` of them (pad
+    ``cap``) and how many there are, which may be more."""
+    rows = spec.expand_rows(g.cap)
+    first = g.label_indptr[spec.root_label]
+    n_cand = g.label_indptr[spec.root_label + 1] - first
+    pos = jnp.arange(rows, dtype=jnp.int32)
+    ids = jnp.take(g.nodes_by_label, first + pos, mode="clip")
+    return jnp.where(pos < n_cand, ids, np.int32(g.cap)), n_cand
 
 
 def expand_stwig_shard(
@@ -95,59 +111,69 @@ def expand_stwig_shard(
     kernels: Kernels | None = None,
 ) -> STwigExpansion:
     """Root candidates and per-root child candidate lists of one STwig on
-    one shard (steps 1-3, one edge-parallel pass over the shard's CSR).
-    ``kernels`` selects the backend for the dense inner ops (default: the
-    jnp reference set) and must be bound statically before ``jit``."""
+    one shard (steps 1-3). The per-child filter runs over the edge slots;
+    everything else runs over the root candidates only, whose rows are
+    then written to their node slots. ``kernels`` selects the backend for
+    the dense inner ops (default: the jnp reference set) and must be bound
+    statically before ``jit``."""
     kern = kernels if kernels is not None else get_kernels("jnp")
     cap, edge_cap = g.cap, g.edge_cap
     n_total = g.n_total
     k = spec.n_children
     C = spec.child_cap
+    iE = np.int32(edge_cap)
 
-    node_slot = jnp.arange(cap, dtype=jnp.int32)
-    gid = g.shard_id.astype(jnp.int32) * cap + node_slot
-
-    # ---- step 1: root candidate mask (node-parallel) ----------------------
-    root_mask = (g.labels == spec.root_label) & (node_slot < g.n_local)
+    # ---- step 1: root candidates (per candidate) ---------------------------
+    root_ids, n_cand = root_candidates(g, spec)
+    live = root_ids < cap
     if spec.root_bound:
-        root_mask &= kern.bitset_lookup(bind.words[spec.root_qnode], gid)
+        gid = g.shard_id.astype(jnp.int32) * cap + root_ids
+        live &= kern.bitset_lookup(bind.words[spec.root_qnode], gid)
 
     # ---- steps 2-3: per-child candidate filter + per-root compaction ------
-    e_pos = jnp.arange(edge_cap, dtype=jnp.int32)
-    e_valid = e_pos < g.n_local_edges
-    root_ok_e = e_valid & jnp.take(root_mask, g.edge_src, mode="clip") & (
-        g.edge_src < cap
-    )
+    # each candidate's CSR segment; a pad or unbound root owns [E, E)
+    lo = jnp.where(live, jnp.take(g.indptr, root_ids, mode="clip"), iE)
+    hi = jnp.where(live, jnp.take(g.indptr, root_ids + 1, mode="clip"), iE)
     dst_labels = jnp.take(g.all_labels, g.indices, mode="clip")
-    # (cap+2,) CSR bounds: row r's edges at [indptr[r], indptr[r+1]), the
-    # ghost row cap owning the pad tail [indptr[cap], edge_cap)
-    indptr_pad = jnp.concatenate(
-        [g.indptr, jnp.full((1,), np.int32(edge_cap), jnp.int32)]
-    )
     words_k = jnp.stack([bind.words[q] for q in spec.child_qnodes])
     cand, cnt = kern.stwig_expand(
         words_k,
         g.indices,
         dst_labels,
-        indptr_pad,
-        root_ok_e,
+        lo,
+        hi,
         child_labels=spec.child_labels,
         child_bound=spec.child_bound,
         child_cap=C,
-        cap=cap,
         n_total=n_total,
-    )  # (k, cap+1, C) ghost-padded candidate ids / (k, cap) counts
+    )  # (k, rows+1, C) ghost-padded candidate ids / (k, rows) counts
 
     # ---- prune roots missing required children ----------------------------
+    ok = live
     for i in range(k):
-        root_mask &= cnt[i] >= spec.child_need[i]
+        ok &= cnt[i] >= spec.child_need[i]
+
+    # ---- each candidate's row to its node slot -----------------------------
+    # one 1-D int32 scatter per column (see `repro.kernels.scan`); the pad
+    # candidates, id cap, all write the ghost row its ghost ids and count 0
+    def to_slots(col, fill):
+        return jnp.full((cap + 1,), fill, jnp.int32).at[root_ids].set(
+            col, indices_are_sorted=True
+        )
+
+    cand_slots = jnp.stack([
+        jnp.stack([to_slots(cand[i, :-1, p], np.int32(n_total)) for p in range(C)],
+                  axis=1)
+        for i in range(k)
+    ])
+    root_mask = to_slots(ok.astype(jnp.int32), np.int32(0))[:cap] > 0
     return STwigExpansion(
-        cand=cand,
-        cnt=jnp.concatenate([cnt, jnp.zeros((k, 1), jnp.int32)], axis=1),
+        cand=cand_slots,
+        cnt=jnp.stack([to_slots(cnt[i], np.int32(0)) for i in range(k)]),
         root_mask=root_mask,
         rank=exclusive_cumsum(root_mask),
-        n_roots=jnp.sum(root_mask, dtype=jnp.int32),
-        overflow=jnp.max(cnt) > C,
+        n_roots=jnp.sum(ok, dtype=jnp.int32),
+        overflow=(jnp.max(cnt) > C) | (n_cand > root_ids.shape[0]),
     )
 
 
@@ -166,14 +192,16 @@ def emit_stwig_round(
     cap, n_total = g.cap, g.n_total
     k = spec.n_children
     C, R = spec.child_cap, spec.root_cap
-    node_slot = jnp.arange(cap, dtype=jnp.int32)
+    root_ids, _ = root_candidates(g, spec)
 
-    # ---- select this round's chunk of roots --------------------------------
+    # ---- select this round's chunk of roots (among the candidates) ---------
     lo = round_idx.astype(jnp.int32) * R
-    sel = exp.root_mask & (exp.rank >= lo) & (exp.rank < lo + R)
-    chunk_pos = jnp.where(sel, exp.rank - lo, np.int32(R))
+    rank = jnp.take(exp.rank, root_ids, mode="clip")
+    sel = jnp.take(exp.root_mask, root_ids, mode="fill", fill_value=False)
+    sel &= (rank >= lo) & (rank < lo + R)
+    chunk_pos = jnp.where(sel, rank - lo, np.int32(R))
     roots_sel = jnp.full((R,), cap, dtype=jnp.int32)
-    roots_sel = roots_sel.at[chunk_pos].set(node_slot, mode="drop")
+    roots_sel = roots_sel.at[chunk_pos].set(root_ids, mode="drop")
     root_live = roots_sel < cap
     root_gid = jnp.where(
         root_live, g.shard_id.astype(jnp.int32) * cap + roots_sel, np.int32(n_total)
@@ -199,12 +227,12 @@ def emit_stwig_round(
     # int32 0/1: a bool array that crosses a reshape is slow to compile on
     # the TPU
     flat_ok = ok.astype(jnp.int32).reshape(-1)
-    rows = [jnp.broadcast_to(root_gid[:, None], (R, P)).reshape(-1)] + [
+    cols = [jnp.broadcast_to(root_gid[:, None], (R, P)).reshape(-1)] + [
         v.reshape(-1) for v in child_vals
     ]  # width columns of (R*P,)
 
     n_rows = jnp.sum(flat_ok, dtype=jnp.int32)
-    cols, valid = compact_rows(rows, flat_ok, spec.rows_cap, np.int32(n_total))
+    cols, valid = compact_rows(cols, flat_ok, spec.rows_cap, np.int32(n_total))
     overflow = (n_rows > spec.rows_cap) | exp.overflow
 
     # ---- step 5: binding contributions (scatter-OR) ------------------------

@@ -5,6 +5,11 @@ per round, per-child candidate cap, output-table rows). These are exactly the
 paper's pipelined-join blocks (§4.2 step 3: "we divide the join into multiple
 rounds ... We use available memory to control the block size"): a capacity is
 a block size, and overflow triggers another round rather than wrong answers.
+
+One size is read from the graph rather than grown: ``root_rows``, the rows
+of an STwig's expansion, is the root label's node count. Only nodes with
+the root's label can be roots, so the expansion runs over those and not
+over every node slot (`repro.core.match.expand_stwig_shard`).
 """
 from __future__ import annotations
 
@@ -37,6 +42,14 @@ class STwigSpec:
     same_label_child_pairs: tuple[tuple[int, int], ...]
     root_label_child_positions: tuple[int, ...]
     child_need: tuple[int, ...]  # per-child multiplicity of its label
+    # rows of the expansion: the root label's node count in the graph the
+    # plan was made on (at least 1); it bounds the label's count on every
+    # shard of that graph
+    root_rows: int
+
+    def expand_rows(self, cap: int) -> int:
+        """Rows of this STwig's expansion on a shard of ``cap`` node slots."""
+        return min(self.root_rows, cap)
 
     @property
     def n_children(self) -> int:
@@ -92,6 +105,7 @@ def caps_from_plan(plan: QueryPlan, base: dict | None = None) -> dict:
 def _spec_for(
     stwig: STwig,
     bound_before: set[int],
+    freq: np.ndarray,
     *,
     root_cap: int,
     child_cap: int,
@@ -133,6 +147,7 @@ def _spec_for(
         same_label_child_pairs=pairs,
         root_label_child_positions=root_kids,
         child_need=need,
+        root_rows=max(1, int(freq[stwig.root_label])),
     )
 
 
@@ -157,6 +172,7 @@ def make_plan(
         _spec_for(
             t,
             bb,
+            freq,
             root_cap=root_cap,
             child_cap=child_cap,
             emission_budget=emission_budget,

@@ -54,6 +54,9 @@ class MatchStats:
     # matching roots per STwig; both backends populate it (sharded reports
     # the max over shards — the shard that drives the round count)
     stwig_roots: list[int] = dataclasses.field(default_factory=list)
+    # rows the STwig expansions ran over, summed over the STwigs: each
+    # STwig's root candidates as its plan sized them (per shard)
+    expand_rows: int = 0
     join_order: list[tuple[int, ...]] = dataclasses.field(default_factory=list)
     n_shards: int = 1
     # device-to-host reads on the query path (`repro.runtime.spans.host_get`)

@@ -5,7 +5,7 @@ per-tile vectorized compaction:
 
   * **mask+ics** — forward pass over edge tiles: the per-child candidate
     filter (dst-label equality ∧ binding-bit membership, bitsets
-    VMEM-resident, out-of-range ids masked False ∧ root candidacy), then an
+    VMEM-resident, out-of-range ids masked False), then an
     in-tile log-doubling inclusive prefix sum of the stacked ``(k, be)``
     mask. A ``(k, 1)`` carry output revisited with a constant index map
     threads the running totals across the sequential grid, so the prefix
@@ -15,18 +15,19 @@ per-tile vectorized compaction:
     an in-tile log-doubling suffix-min of the survivor edge index
     (sentinel = padded length) plus a carried minimum gives, per position,
     the first surviving edge at or after it.
-  * **emit** — a grid over root tiles with the full ``(k, epad)`` prefix
-    arrays VMEM-resident: per root, exact counts from two boundary gathers
+  * **emit** — a grid over tiles of rows (root candidates, each given by
+    its segment bounds ``[lo, hi)``) with the full ``(k, epad)`` prefix
+    arrays VMEM-resident: per row, exact counts from two boundary gathers
     into the prefix sums, the candidate list from a ``child_cap``-step
     vectorized gather chain through ``nxt``, and one whole-block store per
     output. No scalar dynamic stores anywhere.
 
 Edge arrays are padded to a tile multiple (pad dst = ghost ``n_total``,
-``root_ok`` = False), so any edge count — including odd/prime ``E`` —
-keeps full-width tiles; the old fallback halved the tile size until it
-divided ``E``, collapsing to 1-edge tiles for prime ``E``. Root tiles are
-padded the same way (empty segments at the pad sentinel) and sliced off
-the outputs.
+pad label -1, which no child has), so any edge count — including
+odd/prime ``E`` — keeps full-width tiles; the old fallback halved the tile
+size until it divided ``E``, collapsing to 1-edge tiles for prime ``E``.
+Row tiles are padded the same way (empty segments at the pad sentinel)
+and sliced off the outputs.
 
 Oracle: `repro.kernels.stwig_expand.ref.stwig_expand_reference` (same
 scatter-free formulation in pure jnp).
@@ -48,7 +49,6 @@ def _mask_ics_kernel(
     w_ref,      # (k, W) uint32 binding bitsets
     dst_ref,    # (be,) int32 destination ids
     lab_ref,    # (be,) int32 destination labels
-    rok_ref,    # (be,) bool root-candidacy
     mask_ref,   # (k, be) bool out — survivor mask
     ics_ref,    # (k, be) int32 out — global inclusive cumsum of the mask
     carry_ref,  # (k, 1) int32 — running totals, revisited every tile
@@ -65,11 +65,10 @@ def _mask_ics_kernel(
 
     ids = dst_ref[...]
     labs = lab_ref[...]
-    rok = rok_ref[...]
     words = w_ref[...]
     masks = []
     for c in range(k):
-        m = rok & (labs == np.int32(child_labels[c]))
+        m = labs == np.int32(child_labels[c])
         if child_bound[c]:
             m &= lookup_reference(words[c], ids)
         masks.append(m)
@@ -174,19 +173,18 @@ def stwig_expand(
     words_k: jnp.ndarray,     # (k, W) uint32
     dst_ids: jnp.ndarray,     # (E,) int32
     dst_labels: jnp.ndarray,  # (E,) int32
-    indptr: jnp.ndarray,      # (cap+2,) int32 CSR bounds incl. pad tail
-    root_ok: jnp.ndarray,     # (E,) bool
+    lo: jnp.ndarray,          # (rows,) int32 segment starts, pad E
+    hi: jnp.ndarray,          # (rows,) int32 segment ends, pad E
     *,
     child_labels: tuple[int, ...],
     child_bound: tuple[bool, ...],
     child_cap: int,
-    cap: int,
     n_total: int,
     be: int = 2048,
     rt: int = 512,
     interpret: bool = False,
 ) -> tuple[jnp.ndarray, jnp.ndarray]:
-    """Fused filter + compaction: ``cand (k, cap+1, C)``, ``cnt (k, cap)``."""
+    """Fused filter + compaction: ``cand (k, rows+1, C)``, ``cnt (k, rows)``."""
     k = len(child_labels)
     assert k >= 1 and words_k.shape[0] == k
     C = child_cap
@@ -197,8 +195,7 @@ def stwig_expand(
     pad_e = epad - E
     if pad_e:  # full-width tiles for any E (prime E included)
         dst_ids = jnp.pad(dst_ids, (0, pad_e), constant_values=np.int32(n_total))
-        dst_labels = jnp.pad(dst_labels, (0, pad_e))
-        root_ok = jnp.pad(root_ok, (0, pad_e))
+        dst_labels = jnp.pad(dst_labels, (0, pad_e), constant_values=np.int32(-1))
 
     mask, ics, _ = pl.pallas_call(
         functools.partial(
@@ -210,7 +207,6 @@ def stwig_expand(
         grid=(n_tiles,),
         in_specs=[
             pl.BlockSpec(words_k.shape, lambda i: (0, 0)),
-            pl.BlockSpec((be,), lambda i: (i,)),
             pl.BlockSpec((be,), lambda i: (i,)),
             pl.BlockSpec((be,), lambda i: (i,)),
         ],
@@ -225,7 +221,7 @@ def stwig_expand(
             jax.ShapeDtypeStruct((k, 1), jnp.int32),
         ],
         interpret=interpret,
-    )(words_k, dst_ids, dst_labels, root_ok)
+    )(words_k, dst_ids, dst_labels)
 
     nxt, _ = pl.pallas_call(
         functools.partial(_nxt_kernel, k=k, be=be, n_tiles=n_tiles, epad=epad),
@@ -242,14 +238,13 @@ def stwig_expand(
         interpret=interpret,
     )(mask)
 
-    # per-root CSR segment bounds; pad roots get the empty segment
-    # [epad, epad) so they emit zero counts and all-ghost rows
-    lo = indptr[:-1]
-    hi = indptr[1:]
-    R = cap + 1
+    # pad rows of the last tile get the empty segment [epad, epad), so
+    # they emit zero counts and all-ghost rows; one of them is the
+    # contract's ghost row
+    R = lo.shape[0] + 1
     rt = min(rt, R)
     r_tiles = -(-R // rt)
-    rpad = r_tiles * rt - R
+    rpad = r_tiles * rt - lo.shape[0]
     if rpad:
         lo = jnp.pad(lo, (0, rpad), constant_values=np.int32(epad))
         hi = jnp.pad(hi, (0, rpad), constant_values=np.int32(epad))
@@ -274,4 +269,4 @@ def stwig_expand(
         ],
         interpret=interpret,
     )(lo, hi, ics, nxt, dst_ids)
-    return cand[:, :cap + 1], cnt[:, :cap]
+    return cand[:, :R], cnt[:, :R - 1]

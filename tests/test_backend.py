@@ -116,43 +116,55 @@ def test_parity_candidate_filter():
     assert (a == b).all()
 
 
+def _rows_of(indptr, roots, E):
+    """``(lo, hi)`` segment bounds of the rows ``roots`` (node slots, -1 for
+    a pad row, which owns the empty ``[E, E)``)."""
+    roots = np.asarray(roots)
+    lo = np.where(roots >= 0, indptr[np.maximum(roots, 0)], E)
+    hi = np.where(roots >= 0, indptr[np.maximum(roots, 0) + 1], E)
+    return lo.astype(np.int32), hi.astype(np.int32)
+
+
 def _expand_inputs(rng, cap=41, E=160, n_total=300, k=2, child_cap=3,
-                   n_labels=4):
+                   n_labels=4, n_rows=12, n_pad=3):
     src = np.sort(rng.integers(0, cap, E)).astype(np.int32)
-    # (cap+2,) CSR bounds over the edge arrays; indptr[cap+1] == E
-    indptr = np.searchsorted(src, np.arange(cap + 2)).astype(np.int32)
+    # (cap+1,) CSR bounds over the edge arrays
+    indptr = np.searchsorted(src, np.arange(cap + 1)).astype(np.int32)
     dst = rng.integers(0, n_total, E).astype(np.int32)
     labs = rng.integers(0, n_labels, E).astype(np.int32)
-    rok = rng.random(E) < 0.8
     W = n_words(n_total + 1)
     words = rng.integers(0, 2**32, (k, W), dtype=np.uint32)
-    args = tuple(jnp.asarray(x) for x in (words, dst, labs, indptr, rok))
+    # candidate rows: some node slots in order, then pad rows
+    roots = np.concatenate([
+        np.sort(rng.choice(cap, n_rows, replace=False)), np.full(n_pad, -1)
+    ])
+    lo, hi = _rows_of(indptr, roots, E)
+    args = tuple(jnp.asarray(x) for x in (words, dst, labs, lo, hi))
     kw = dict(
         child_labels=(1, 2),
         child_bound=(True, False),
         child_cap=child_cap,
-        cap=cap,
         n_total=n_total,
     )
     return args, kw
 
 
 def _expand_oracle_np(args, kw):
-    """Host-side reference: per root r, the surviving dsts of the edges in
-    [indptr[r], indptr[r+1]) in edge order; exact counts."""
+    """Host-side reference: per row r, the surviving dsts of the edges in
+    [lo[r], hi[r]) in edge order; exact counts; a last all-ghost row."""
     from repro.kernels.bitset.ref import bitset_test_np
 
-    words, dst, labs, indptr, rok = (np.asarray(a) for a in args)
+    words, dst, labs, lo, hi = (np.asarray(a) for a in args)
     k = len(kw["child_labels"])
-    cap, C, n_total = kw["cap"], kw["child_cap"], kw["n_total"]
-    cand = np.full((k, cap + 1, C), n_total, np.int32)
-    cnt = np.zeros((k, cap), np.int32)
+    rows, C, n_total = lo.shape[0], kw["child_cap"], kw["n_total"]
+    cand = np.full((k, rows + 1, C), n_total, np.int32)
+    cnt = np.zeros((k, rows), np.int32)
     for c in range(k):
-        m = rok & (labs == kw["child_labels"][c])
+        m = labs == kw["child_labels"][c]
         if kw["child_bound"][c]:
             m &= bitset_test_np(words[c], dst)
-        for r in range(cap):
-            sel = dst[indptr[r]:indptr[r + 1]][m[indptr[r]:indptr[r + 1]]]
+        for r in range(rows):
+            sel = dst[lo[r]:hi[r]][m[lo[r]:hi[r]]]
             cnt[c, r] = len(sel)
             cand[c, r, : min(len(sel), C)] = sel[:C]
     return cand, cnt
@@ -177,19 +189,21 @@ def test_parity_stwig_expand():
 def test_stwig_expand_counts_grow_past_child_cap():
     """cnt is EXACT even when a root has more survivors than child_cap —
     the overflow signal the engine's adaptive retry keys on."""
-    # one root owns every edge, labels/bitsets fully permissive
-    E, cap, n_total = 64, 5, 100
-    indptr = np.zeros(cap + 2, np.int32)
-    indptr[1:] = E  # root 0 owns [0, E)
+    # row 0 owns every edge, labels/bitsets fully permissive; rows 1-2 are
+    # an empty segment and a pad row
+    E, n_total = 64, 100
+    lo = np.array([0, E // 2, E], np.int32)
+    hi = np.array([E, E // 2, E], np.int32)
     dst = np.arange(E, dtype=np.int32)
     labs = np.full(E, 1, np.int32)
-    rok = np.ones(E, bool)
     words = np.full((2, n_words(n_total + 1)), 0xFFFFFFFF, np.uint32)
-    args = tuple(jnp.asarray(x) for x in (words, dst, labs, indptr, rok))
+    args = tuple(jnp.asarray(x) for x in (words, dst, labs, lo, hi))
     kw = dict(child_labels=(1, 1), child_bound=(True, False), child_cap=3,
-              cap=cap, n_total=n_total)
+              n_total=n_total)
     for kern in (JNP, PAL):
         cand, cnt = kern.stwig_expand(*args, **kw)
+        assert np.asarray(cnt).shape == (2, 3)
+        assert np.asarray(cand).shape == (2, 4, 3)
         assert (np.asarray(cnt)[:, 0] == E).all()      # exact, not clamped
         assert (np.asarray(cnt)[:, 1:] == 0).all()
         assert (np.asarray(cand)[:, 0] == [0, 1, 2]).all()  # first C, in order
@@ -210,14 +224,14 @@ def test_stwig_expand_segment_straddles_tiles():
         np.zeros(4, np.int32), np.full(E - 8, 1, np.int32),
         np.full(4, 2, np.int32),
     ])
-    indptr = np.searchsorted(src, np.arange(cap + 2)).astype(np.int32)
+    indptr = np.searchsorted(src, np.arange(cap + 1)).astype(np.int32)
     dst = rng.integers(0, n_total, E).astype(np.int32)
     labs = rng.integers(0, 2, E).astype(np.int32)
-    rok = np.ones(E, bool)
     words = rng.integers(0, 2**32, (2, n_words(n_total + 1)), dtype=np.uint32)
-    args = tuple(jnp.asarray(x) for x in (words, dst, labs, indptr, rok))
+    lo, hi = _rows_of(indptr, [0, 1, 2, -1], E)
+    args = tuple(jnp.asarray(x) for x in (words, dst, labs, lo, hi))
     kw = dict(child_labels=(1, 0), child_bound=(True, False), child_cap=6,
-              cap=cap, n_total=n_total)
+              n_total=n_total)
     cj, nj = JNP.stwig_expand(*args, **kw)
     cp, np_ = stwig_expand(*args, **kw, be=be, interpret=True)
     assert (np.asarray(nj) == np.asarray(np_)).all()
@@ -231,15 +245,38 @@ def test_parity_stwig_expand_edge_lengths(E):
     """Pinned regression for the degenerate tile fallback: the old kernel
     halved the tile size until it divided E — be=1 (an E-step grid) for
     prime E. The padded-tile kernel must stay exact for any E, including
-    a tile size that does NOT divide E (forced be=32)."""
+    a tile size that does NOT divide E (forced be=32); a child of label 0,
+    the label jnp.pad would give the pad edges, sees none of them."""
     from repro.kernels.stwig_expand.stwig_expand import stwig_expand
 
     args, kw = _expand_inputs(np.random.default_rng(17), E=E)
+    kw = dict(kw, child_labels=(0, 2))
     _assert_expand_parity(args, kw)
     cj, nj = JNP.stwig_expand(*args, **kw)
     cp, np_ = stwig_expand(*args, **kw, be=32, interpret=True)
     assert (np.asarray(nj) == np.asarray(np_)).all()
     assert (np.asarray(cj) == np.asarray(cp)).all()
+
+
+@pytest.mark.parametrize("rows, rt", [(1, 512), (7, 4), (8, 4), (600, 512)],
+                         ids=["one-row", "row-tiles-pad", "row-tiles-full",
+                              "two-default-tiles"])
+def test_parity_stwig_expand_row_tiles(rows, rt):
+    """The Pallas emit grid tiles the rows: any row count, including one
+    that fills its tiles exactly (the ghost row then starts a tile of its
+    own), gives the jnp op's lists and counts."""
+    from repro.kernels.stwig_expand.stwig_expand import stwig_expand
+
+    rng = np.random.default_rng(rows)
+    cap = max(rows, 41)
+    args, kw = _expand_inputs(rng, cap=cap, E=4 * cap, n_rows=rows - 1, n_pad=1)
+    cj, nj = JNP.stwig_expand(*args, **kw)
+    cp, np_ = stwig_expand(*args, **kw, rt=rt, interpret=True)
+    assert np.asarray(cp).shape == (2, rows + 1, kw["child_cap"])
+    assert (np.asarray(nj) == np.asarray(np_)).all()
+    assert (np.asarray(cj) == np.asarray(cp)).all()
+    co, no = _expand_oracle_np(args, kw)
+    assert (np.asarray(nj) == no).all() and (np.asarray(cj) == co).all()
 
 
 def test_parity_hash_join_probe():

@@ -1,5 +1,7 @@
 """Distributed matcher (shard_map, 8 simulated machines): exactness vs the
-oracle, disjointness of per-shard results, and the OR-allreduce collective.
+oracle, disjointness of per-shard results, and the OR-allreduce collective;
+and on 2 of them, exactness where an STwig expands over fewer rows than a
+shard has node slots.
 
 Multi-device tests run in a subprocess so the main test session keeps a
 single CPU device (per the dry-run isolation rule).
@@ -64,6 +66,22 @@ for _ in range(3):
     checks.append(got == want and res.complete
                   and len(res.rows) == len(got))  # no duplicates in union
 out["dist_exact"] = all(checks) and len(checks) >= 2
+
+# --- two shards of 1,500-odd node slots, more than any STwig's rows ------
+from test_expand_rows import CAPS, _queries
+g = generators.rmat(3000, 9000, 100, seed=1, symmetrize=True)
+pg = PartitionedGraph.build(g, 2)
+dm = DistributedMatcher(pg, Mesh(np.array(jax.devices()[:2]), ("data",)))
+rows, exact = [], []
+for q in _queries(g, 2, 4):
+    plan = dm.plan(q, **CAPS)
+    rows += [sp.expand_rows(pg.cap) for sp in plan.specs]
+    res = dm.match(q, plan)
+    got = set(map(tuple, res.rows.tolist()))
+    exact.append(res.complete and got == nx_oracle(g, q)
+                 and len(res.rows) == len(got))
+out["rows_below_cap"] = bool(rows) and max(rows) < pg.cap
+out["rows_below_cap_exact"] = bool(exact) and all(exact)
 print(json.dumps(out))
 """ % (TESTS,)
 
@@ -87,3 +105,11 @@ def test_or_allreduce(dist_results):
 
 def test_distributed_matches_oracle_no_dedup(dist_results):
     assert dist_results["dist_exact"]
+
+
+def test_distributed_matches_oracle_with_rows_below_cap(dist_results):
+    """Each shard expands an STwig over its root candidates only, bounded
+    by the plan's global ``root_rows``: fewer rows than the shard's node
+    slots, and the answers stay exact."""
+    assert dist_results["rows_below_cap"]
+    assert dist_results["rows_below_cap_exact"]

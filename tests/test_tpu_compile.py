@@ -4,8 +4,9 @@ Every op that ``kernels="auto"`` selects on a TPU (the `TPU_OPS` table),
 every repaired Pallas kernel, and the local engine's steps (the two halves
 of an STwig match, and a join) are compiled by the TPU compiler at the
 widths ``chip_smoke.py`` runs: the US-Patents-shaped graph's 3,774,768
-node slots and about 33M edge slots, and the plan caps its queries get
-(2^18-row STwig tables, 2^16-row join output, dup window 64). Nothing
+node slots and about 33M edge slots, the rows an STwig's expansion gets
+there (one label's node count), and the plan caps its queries
+get (2^18-row STwig tables, 2^16-row join output, dup window 64). Nothing
 runs; a kernel the chip's compiler refuses, or a program that stops
 compiling in seconds, fails here at no chip time. This is the only file
 that describes the chip.
@@ -13,7 +14,9 @@ that describes the chip.
 from __future__ import annotations
 
 import functools
+import math
 import os
+import re
 import time
 
 import pytest
@@ -31,6 +34,9 @@ from repro.core.backend import (
 
 NODES = 3_774_768             # node slots of the one-shard Patents graph
 EDGE_SLOTS = 2 * 16_522_438   # symmetrised CSR slots (upper bound)
+ROOT_ROWS = 9_031             # an STwig's expansion rows: the node count
+#                               of one of 418 uniform labels
+LABELS = 418
 ROWS = 1 << 18                # STwig table rows: make_plan's emission budget
 JOIN_OUT = 1 << 16            # make_plan's join_rows_cap
 DUP = 64                      # make_plan's join_dup_cap
@@ -100,10 +106,10 @@ def _op_args(op: str, s):
     if op == "stwig_expand":        # a three-child STwig, one child bound
         return (
             s((3, WORDS), u32), s((EDGE_SLOTS,), i32), s((EDGE_SLOTS,), i32),
-            s((NODES + 2,), i32), s((EDGE_SLOTS,), b),
+            s((ROOT_ROWS,), i32), s((ROOT_ROWS,), i32),
         ), (), dict(
             child_labels=(1, 2, 3), child_bound=(True, False, False),
-            child_cap=CHILD_CAP, cap=NODES, n_total=NODES,
+            child_cap=CHILD_CAP, n_total=NODES,
         )
     if op == "hash_join_probe":     # two STwig tables on a 2-column key
         return (
@@ -145,6 +151,32 @@ def test_repaired_pallas_kernel_compiles_for_v5e(one_chip, op):
     assert "tpu_custom_call" in hlo
 
 
+def _shard_graph(s):
+    from repro.core.match import ShardGraph
+
+    i32 = jnp.int32
+    return ShardGraph(
+        labels=s((NODES,), i32), indptr=s((NODES + 1,), i32),
+        indices=s((EDGE_SLOTS,), i32), n_local=s((), i32),
+        nodes_by_label=s((NODES,), i32), label_indptr=s((LABELS + 1,), i32),
+        shard_id=s((), i32), all_labels=s((NODES + 1,), i32),
+    )
+
+
+def _star_spec(k, child_cap, *, bound):
+    from repro.core.plan import STwigSpec
+
+    root_cap = max(1, ROWS // child_cap**k)
+    return STwigSpec(
+        root_label=3, child_labels=tuple(range(5, 5 + k)), root_qnode=0,
+        child_qnodes=tuple(range(1, k + 1)), root_bound=bound,
+        child_bound=(bound,) + (False,) * (k - 1), root_cap=root_cap,
+        child_cap=child_cap, rows_cap=root_cap * child_cap**k,
+        same_label_child_pairs=(), root_label_child_positions=(),
+        child_need=(1,) * k, root_rows=ROOT_ROWS,
+    )
+
+
 @pytest.mark.parametrize("child_cap", [CHILD_CAP, 64])
 def test_local_match_step_compiles_for_v5e(one_chip, child_cap):
     """The local engine's two STwig halves over the whole graph, built as
@@ -154,27 +186,13 @@ def test_local_match_step_compiles_for_v5e(one_chip, child_cap):
     expansion outlives the expand step and is the emit step's argument:
     each step's arguments, outputs and temporaries must fit a 16 GB chip."""
     from repro.core.engine import stwig_step_fns
-    from repro.core.match import Bindings, ShardGraph, STwigExpansion
-    from repro.core.plan import STwigSpec
+    from repro.core.match import Bindings, STwigExpansion
 
     s = _abstract(one_chip)
     i32, b = jnp.int32, jnp.bool_
-    g = ShardGraph(
-        labels=s((NODES,), i32), indptr=s((NODES + 1,), i32),
-        indices=s((EDGE_SLOTS,), i32), edge_src=s((EDGE_SLOTS,), i32),
-        n_local=s((), i32), n_local_edges=s((), i32), shard_id=s((), i32),
-        all_labels=s((NODES + 1,), i32),
-    )
+    g = _shard_graph(s)
     k = 3
-    root_cap = max(1, ROWS // child_cap**k)
-    spec = STwigSpec(
-        root_label=3, child_labels=(5, 6, 7), root_qnode=0,
-        child_qnodes=(1, 2, 3), root_bound=True,
-        child_bound=(True, False, False), root_cap=root_cap,
-        child_cap=child_cap, rows_cap=root_cap * child_cap**k,
-        same_label_child_pairs=(), root_label_child_positions=(),
-        child_need=(1, 1, 1),
-    )
+    spec = _star_spec(k, child_cap, bound=True)
     expand, emit = stwig_step_fns(spec, TPU_KERNELS, WORDS)
     exp = STwigExpansion(
         cand=s((k, NODES + 1, child_cap), i32), cnt=s((k, NODES + 1), i32),
@@ -190,6 +208,9 @@ def test_local_match_step_compiles_for_v5e(one_chip, child_cap):
             lambda g, exp, r: emit(g, exp, round_idx=r), g, exp, s((), i32),
         ),
     }
+    out = jax.eval_shape(expand, g, Bindings(s((k + 1, WORDS), jnp.uint32)))
+    assert jax.tree.map(lambda x: (x.shape, x.dtype), out) == jax.tree.map(
+        lambda x: (x.shape, x.dtype), exp)
     for name, compiled in steps.items():
         mem = compiled.memory_analysis()
         total = (
@@ -197,6 +218,36 @@ def test_local_match_step_compiles_for_v5e(one_chip, child_cap):
             + mem.output_size_in_bytes
         )
         assert total < 16e9, f"{name}: {total / 1e9:.2f} GB"
+
+
+def _gather_widths(hlo: str) -> list[int]:
+    """The number of elements each gather of an HLO module produces."""
+    widths = []
+    for m in re.finditer(r"= \w+\[([\d,]*)\]\S* gather\(", hlo):
+        widths.append(math.prod(int(d) for d in m.group(1).split(",") if d))
+    return widths
+
+
+def test_expand_gathers_over_root_candidates_for_v5e(one_chip):
+    """The expansion of the 10-node star (9 children, child cap 4, as the
+    plan's emission budget gives it) gathers over its root candidates
+    only: the one gather over every edge slot is that of the destination
+    labels, and no other is wider than the candidate rows."""
+    from repro.core.engine import stwig_step_fns
+    from repro.core.match import Bindings
+
+    s = _abstract(one_chip)
+    k = 9
+    spec = _star_spec(k, 4, bound=False)
+    expand, _ = stwig_step_fns(spec, TPU_KERNELS, WORDS)
+    hlo = _compile(
+        lambda g, words: expand(g, Bindings(words)),
+        _shard_graph(s), s((k + 1, WORDS), jnp.uint32),
+    ).as_text()
+    widths = _gather_widths(hlo)
+    rows = spec.expand_rows(NODES)
+    assert len(widths) >= k * (2 + 2 * spec.child_cap)
+    assert sorted(w for w in widths if w > rows) == [EDGE_SLOTS]
 
 
 def test_local_join_step_compiles_for_v5e(one_chip):
